@@ -13,7 +13,8 @@ for (B, Tq, Tk, H, KV, hd, bq, bk, causal, window, dtype) in [
     q = jnp.asarray(rng.randn(B, Tq, H, hd), dtype)
     k = jnp.asarray(rng.randn(B, Tk, KV, hd), dtype)
     v = jnp.asarray(rng.randn(B, Tk, KV, hd), dtype)
-    a = flash_attention_op(q, k, v, causal=causal, window=window, block_q=bq, block_k=bk)
+    a = flash_attention_op(q, k, v, causal=causal, window=window, block_q=bq, block_k=bk,
+                           interpret=True)
     b = flash_attention_op(q, k, v, causal=causal, window=window, impl="ref")
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=tol, atol=tol)

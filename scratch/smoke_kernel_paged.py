@@ -17,7 +17,8 @@ for (B, KV, G, hd, ps, P, dtype) in [
     tables = jnp.asarray(rng.permutation(npages)[:B * P].reshape(B, P), jnp.int32)
     seq = jnp.asarray(rng.randint(1, P * ps - 1, size=B), jnp.int32)
     for window in (1 << 30, ps * 2 + 3):
-        out_k = paged_decode_attention_op(q, kp, vp, tables, seq, window=window, impl="kernel")
+        out_k = paged_decode_attention_op(q, kp, vp, tables, seq, window=window, impl="kernel",
+                                          interpret=True)
         out_r = paged_decode_attention_op(q, kp, vp, tables, seq, window=window, impl="ref")
         tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
         np.testing.assert_allclose(np.asarray(out_k, np.float32), np.asarray(out_r, np.float32),

@@ -21,7 +21,7 @@ def flash_attention_op(
     block_q: int = 128,
     block_k: int = 128,
     impl: str = "kernel",
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     if impl == "ref":
         return flash_attention_ref(q, k, v, causal=causal, window=window)

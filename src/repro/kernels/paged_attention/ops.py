@@ -2,8 +2,8 @@
 
 Handles layout adaptation from the serving engine's conventions
 ([B, H, hd] queries, [num_pages, L, ps, KV, hd] pools, NO_BLOCK sentinels)
-to the kernel's per-layer grouped layout, and exposes ``interpret=`` for
-CPU validation (the TPU target compiles the same callable).
+to the kernel's per-layer grouped layout.  ``interpret`` defaults to False
+(the TPU target); CPU validation passes ``interpret=True`` explicitly.
 """
 from __future__ import annotations
 
@@ -27,7 +27,7 @@ def paged_decode_attention_op(
     seq_lens: jnp.ndarray,      # [B] int32 — cache length incl. current token
     window: int = FULL_WINDOW,
     impl: str = "kernel",
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Returns [B, H, hd]."""
     B, H, hd = q.shape

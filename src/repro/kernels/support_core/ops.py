@@ -43,15 +43,15 @@ def support_core_burst(
         max_per_req=max_blocks_per_req, interpret=interpret)
     new_state = FreeListState(
         free_stack=new_stack,
-        free_top=new_top[:, 0],
+        free_top=new_top,
         owner=new_owner,
         refcount=new_refcount,
         capacity=state.capacity,
-        alloc_count=new_alloc[:, 0],
-        free_count=new_free[:, 0],
-        fail_count=new_fail[:, 0],
-        used=new_used[:, 0],
-        peak_used=new_peak[:, 0],
+        alloc_count=new_alloc,
+        free_count=new_free,
+        fail_count=new_fail,
+        used=new_used,
+        peak_used=new_peak,
         # the fused free-list kernel never splits/merges runs; the buddy
         # telemetry counters pass through untouched (jnp-only policy)
         split_count=state.split_count,

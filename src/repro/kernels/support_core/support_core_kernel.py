@@ -3,39 +3,44 @@
 The paper's support-core is a deliberately *lightweight* core: integer-only,
 no FP/vector units (§2.4), with the whole segregated metadata in its private
 L1 (§5.1).  The TPU-native analogue is a single VPU-only kernel — zero MXU
-work — with ``free_stack [C, N]`` and ``owner [C, N]`` (plus the [C] counter
-vectors) resident in VMEM for the whole burst, playing the role of the
-support-core's L1: one grid step services a whole scheduled HMQ batch, and
-the metadata makes exactly one HBM→VMEM→HBM round trip per burst instead of
-one per XLA op (the ``"jnp"`` backend's scan + gathers + scatters each
-re-touch HBM).
+work — with ``free_stack``, ``owner`` and ``refcount`` resident in VMEM for
+the whole burst, playing the role of the support-core's L1, and the queue
+plus the [C] counters in SMEM, read and written by the TPU's scalar unit:
+one launch services a whole scheduled HMQ batch, and the metadata makes
+exactly one HBM→VMEM→HBM round trip per burst instead of one per XLA op
+(the ``"jnp"`` backend's scan + gathers + scatters each re-touch HBM).
 
 Scope (DESIGN.md §8): everything in
 :func:`repro.core.support_core._step_scheduled_jnp` for an
 already-``hmq.schedule``d queue —
 
-  * sequential-skip malloc grants (the [C]-state scan over the queue),
-  * the batched stack gather + owner-map update,
-  * scatter-based single-block frees,
-  * the FREE_ALL owner sweep (an accumulated masked-OR over the queue's
-    FREE_ALL packets — the host path's sorted-lane-list binary search exists
-    to avoid materializing [Q, C, N] in HBM, which a VMEM-resident kernel
-    never does, so the simpler O(Q·C·N/vector-width) sweep wins here),
-  * the deferred-free compaction + stack append,
+  * sequential-skip malloc grants: a scalar ``fori_loop`` over the queue
+    whose only state is the per-class stack top (SMEM), so a failed request
+    consumes nothing for its successors;
+  * the stack gather + owner-map/refcount update, one 128-lane row at a
+    time for each granted block;
+  * single-block frees (row read-modify-write counts) and the FREE_ALL
+    owner sweep (a masked OR over the class's rows per FREE_ALL packet —
+    the host path's sorted-lane-list binary search exists to avoid
+    materializing [Q, C, N] in HBM, which a VMEM-resident kernel never
+    does);
+  * the deferred-free compaction + stack append: per 128-id row, the
+    in-row prefix count and the rank-select of returned ids are [128, 128]
+    compare-and-sum passes, written at the running stack offset;
   * all counters (used / peak_used / alloc_count / free_count / fail_count).
 
 HMQ scheduling (the priority/round-robin sort) and response unpermutation
 stay in the host-side dispatcher — they are queue bookkeeping, not metadata
-mutation.  The grant recurrence stays a `lax.scan`: a request's grant
-depends on which EARLIER requests of its class were granted (failures
-consume nothing), a true prefix recurrence with [C]-vector state that no
-fixed number of cumsum passes can replace.
+mutation.
 
-Shapes: Q requests, C size classes, N stack capacity, R max blocks/request.
-VMEM: free_stack + owner + refcount dominate at 3·C·N·4 bytes in + the same
-out (C=8, N=64k → 6 MB in + 6 MB out); queue and counters are O(Q + C).
-Frees are refcount decrements (DESIGN.md §12): the freed-id compaction and
-owner clear apply only to blocks whose refcount reaches 0.
+Layout: the wrapper pads N up to a multiple of ``_TILE`` ids and views each
+``[C, N]`` plane as ``[C, T, 128]`` (T rows of 128 ids), so every vector op
+is a whole number of (8, 128) int32 tiles and no op needs a gather, a
+scatter or a scan, none of which Mosaic lowers here.  Padded ids carry
+owner -1 and are never granted (they sit above every stack top), so they
+never change state.  Frees are refcount decrements (DESIGN.md §12): the
+freed-id compaction and owner clear apply only to blocks whose refcount
+reaches 0.
 """
 from __future__ import annotations
 
@@ -46,160 +51,207 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.packets import FREE_ALL, NO_BLOCK, OP_FREE, OP_MALLOC, OP_REFILL
+from ...core.packets import (FREE_ALL, NO_BLOCK, OP_FREE, OP_MALLOC,
+                             OP_MALLOC_RUN, OP_REFILL)
+
+_LANES = 128                 # ids per row (the vreg lane width)
+_SUB = 8                     # rows per vector chunk (the int32 sublane count)
+_TILE = _LANES * _SUB        # ids per chunk; N is padded to a multiple
+_FA_SHIFT = 20               # FREE_ALL hit bit in the free-count scratch
+_COUNT_MASK = (1 << _FA_SHIFT) - 1
+
+
+def _lane_iota():
+    return jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+
+def _read_id(ref, c, p):
+    """Scalar ``ref[c, p // 128, p % 128]`` via one row load + masked sum."""
+    row = ref[c, pl.ds(p // _LANES, 1), :]                  # [1, 128]
+    return jnp.sum(jnp.where(_lane_iota() == p % _LANES, row, 0))
+
+
+def _write_id(ref, c, p, value):
+    """``ref[c, p // 128, p % 128] = value`` as one row read-modify-write."""
+    r = pl.ds(p // _LANES, 1)
+    ref[c, r, :] = jnp.where(_lane_iota() == p % _LANES, value, ref[c, r, :])
 
 
 def _kernel(
-    # --- scheduled queue (in): SCALAR-PREFETCH operands (DESIGN.md §13) —
-    # small int32 control words available in SMEM before the kernel body
-    # runs, the TPU analogue of the support-core reading its HMQ request
-    # ring ahead of touching metadata.  Crucially they are runtime DATA:
-    # namespaced size-class ids arrive here per launch (traced through the
-    # burst builder), so one compiled kernel serves every engine shard.
-    op_ref,         # [Q] int32
-    lane_ref,       # [Q] int32
-    cls_ref,        # [Q] int32
-    arg_ref,        # [Q] int32
-    # --- segregated metadata (in) ---
-    stack_ref,      # [C, N] int32
-    top_ref,        # [C, 1] int32
-    owner_ref,      # [C, N] int32
-    refcount_ref,   # [C, N] int32
-    alloc_cnt_ref,  # [C, 1] int32
-    free_cnt_ref,   # [C, 1] int32
-    fail_cnt_ref,   # [C, 1] int32
-    used_ref,       # [C, 1] int32
-    peak_ref,       # [C, 1] int32
-    # --- segregated metadata (out) ---
-    new_stack_ref,  # [C, N] int32
-    new_top_ref,    # [C, 1] int32
-    new_owner_ref,  # [C, N] int32
-    new_refcount_ref,  # [C, N] int32
-    new_alloc_ref,  # [C, 1] int32
-    new_free_ref,   # [C, 1] int32
-    new_fail_ref,   # [C, 1] int32
-    new_used_ref,   # [C, 1] int32
-    new_peak_ref,   # [C, 1] int32
-    # --- responses (out, scheduled order) ---
-    blocks_ref,     # [Q, R] int32
+    # --- scheduled queue (SMEM, [Q] int32): runtime DATA, so namespaced
+    # size-class ids arrive per launch and one compiled kernel serves every
+    # engine shard (DESIGN.md §13) ---
+    op_ref, lane_ref, cls_ref, arg_ref,
+    # --- counters (SMEM, [C] int32) ---
+    top_ref, alloc_cnt_ref, free_cnt_ref, fail_cnt_ref, used_ref, peak_ref,
+    # --- segregated metadata planes (VMEM, [C, T, 128] int32) ---
+    stack_ref, owner_ref, refcount_ref,
+    # --- outputs: planes (VMEM), counters (SMEM), responses (SMEM) ---
+    new_stack_ref, new_owner_ref, new_refcount_ref,
+    new_top_ref, new_alloc_ref, new_free_ref, new_fail_ref, new_used_ref,
+    new_peak_ref,
+    blocks_ref,     # [Q * R] int32, scheduled order, row-major
     ok_ref,         # [Q] int32
+    # --- scratch ---
+    cnt_ref,        # VMEM [C, T, 128]: per-block reference drops, then the
+    #                 returned-block mask
     *,
-    num_classes: int,
+    num_blocks: int,
     max_per_req: int,
 ):
-    C = num_classes
+    C, T, _ = stack_ref.shape
+    Q = op_ref.shape[0]
     R = max_per_req
+    N = num_blocks
+    chunks = T // _SUB
 
-    op = op_ref[...]
-    lane = lane_ref[...]
-    Q = op.shape[0]
-    cls = jnp.clip(cls_ref[...], 0, C - 1)
-    arg = arg_ref[...]
-    is_malloc = (op == OP_MALLOC) | (op == OP_REFILL)
-    is_free = op == OP_FREE
-    want = jnp.where(is_malloc, jnp.maximum(arg, 0), 0)
-    want = jnp.where(want <= R, want, 0)                    # overwide -> fail
+    def chunk(k):
+        return pl.ds(pl.multiple_of(k * _SUB, _SUB), _SUB)
 
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (Q, C), 1)
-              == cls[:, None]).astype(jnp.int32)            # [Q, C]
-    tops = top_ref[:, 0]                                    # [C]
+    # ---- working copies: every mutation below lands in the outputs ----
+    for c in range(C):
+        def copy_body(k, carry, c=c):
+            rows = chunk(k)
+            new_stack_ref[c, rows, :] = stack_ref[c, rows, :]
+            new_owner_ref[c, rows, :] = owner_ref[c, rows, :]
+            new_refcount_ref[c, rows, :] = refcount_ref[c, rows, :]
+            cnt_ref[c, rows, :] = jnp.zeros((_SUB, _LANES), jnp.int32)
+            return carry
+        jax.lax.fori_loop(0, chunks, copy_body, 0)
+        new_top_ref[c] = top_ref[c]
+        new_fail_ref[c] = fail_cnt_ref[c]
 
-    # ---- malloc phase: sequential-skip grants (see module docstring on why
-    # this stays a scan) ----
-    def grant_body(consumed, xs):
-        want_i, onehot_i, is_m_i = xs
-        my = jnp.sum(onehot_i * consumed)
-        av = jnp.sum(onehot_i * tops)
-        ok_i = is_m_i & (want_i > 0) & (my + want_i <= av)
-        consumed = consumed + jnp.where(ok_i, want_i, 0) * onehot_i
-        return consumed, (ok_i, my)
+    def clear_body(i, carry):
+        blocks_ref[i] = jnp.int32(NO_BLOCK)
+        return carry
+    jax.lax.fori_loop(0, Q * R, clear_body, 0)
 
-    _, (ok, my_goff) = jax.lax.scan(
-        grant_body, jnp.zeros((C,), jnp.int32), (want, onehot, is_malloc))
-    fail = is_malloc & ~ok
-    granted = jnp.where(ok, want, 0)
-    granted_c = granted[:, None] * onehot
+    # ---- malloc phase: sequential-skip grants served from the pre-step
+    # stack.  ``new_top[c]`` is the class's unconsumed top: a request of
+    # want w is granted iff w <= new_top[c], and then takes the w ids just
+    # below it (request i's my_goff == top[c] - new_top[c]). ----
+    def grant_body(i, carry):
+        op = op_ref[i]
+        c = jnp.clip(cls_ref[i], 0, C - 1)
+        arg = arg_ref[i]
+        is_m = (op == OP_MALLOC) | (op == OP_REFILL) | (op == OP_MALLOC_RUN)
+        want = jnp.where(is_m, jnp.maximum(arg, 0), 0)
+        want = jnp.where(want <= R, want, 0)                # overwide -> fail
+        avail = new_top_ref[c]
+        ok = is_m & (want > 0) & (want <= avail)
+        ok_ref[i] = ok.astype(jnp.int32)
+        new_fail_ref[c] = new_fail_ref[c] + (is_m & ~ok).astype(jnp.int32)
 
-    # Stack gather: request i takes stack[c, top-1-my_goff-j] for j < granted.
-    j = jax.lax.broadcasted_iota(jnp.int32, (Q, R), 1)
-    top_i = jnp.sum(onehot * tops[None, :], axis=1)         # [Q]
-    pos = top_i[:, None] - 1 - my_goff[:, None] - j         # [Q, R]
-    take = ok[:, None] & (j < granted[:, None])
-    safe_pos = jnp.where(take, pos, 0)
-    stack = stack_ref[...]
-    blocks = jnp.where(take, stack[cls[:, None], safe_pos], NO_BLOCK)
-    blocks_ref[...] = blocks
-    ok_ref[...] = ok.astype(jnp.int32)
+        @pl.when(ok)
+        def _grant():
+            new_top_ref[c] = avail - want
+            lane = lane_ref[i]
 
-    # Owner-map update (positive OOB sentinels drop masked slots — JAX wraps
-    # negative indices even under mode="drop").
-    N = stack.shape[1]
-    flat_cls = jnp.broadcast_to(cls[:, None], (Q, R)).reshape(-1)
-    flat_blk = blocks.reshape(-1)
-    flat_lane = jnp.broadcast_to(lane[:, None], (Q, R)).reshape(-1)
-    flat_take = take.reshape(-1)
-    upd_idx_c = jnp.where(flat_take, flat_cls, C)
-    upd_idx_b = jnp.where(flat_take, flat_blk, N)
-    owner = owner_ref[...].at[upd_idx_c, upd_idx_b].set(flat_lane, mode="drop")
-    # Fresh grants carry exactly one reference (DESIGN.md §12).
-    refcount = refcount_ref[...].at[upd_idx_c, upd_idx_b].set(1, mode="drop")
+            def take(j, carry):
+                blk = _read_id(stack_ref, c, avail - 1 - j)
+                blocks_ref[i * R + j] = blk
 
-    taken_per_class = jnp.sum(granted_c, axis=0)            # [C]
-    top_after_alloc = tops - taken_per_class
-    used_after_alloc = used_ref[:, 0] + taken_per_class
-    new_peak_ref[...] = jnp.maximum(peak_ref[:, 0], used_after_alloc)[:, None]
+                # out-of-range ids (a corrupt stack) are dropped, as the
+                # reference's mode="drop" scatter drops them
+                @pl.when((blk >= 0) & (blk < N))
+                def _own():
+                    _write_id(new_owner_ref, c, blk, lane)
+                    # fresh grants carry exactly one reference (§12)
+                    _write_id(new_refcount_ref, c, blk, 1)
+                return carry
+            jax.lax.fori_loop(0, want, take, 0)
+        return carry
+    jax.lax.fori_loop(0, Q, grant_body, 0)
 
-    # ---- free phase (deferred append) ----
-    # Single-block frees scatter-ADD (class, arg) hits — each packet drops
-    # one reference, so K frees of a shared page decrement K times.
-    is_single = is_free & (arg >= 0)
-    sgl_c = jnp.where(is_single, cls, C)
-    sgl_b = jnp.where(is_single & (arg < N), arg, N)
-    single_cnt = jnp.zeros((C, N), jnp.int32).at[sgl_c, sgl_b].add(
-        1, mode="drop")
+    # ---- free phase (deferred: frees cannot serve this step's mallocs).
+    # Each single-block free drops one reference; a FREE_ALL sets the hit
+    # bit of every block its lane owns (at most 1 per block, idempotent).
+    # The post-alloc owner map is used, so a block granted this step can be
+    # freed this step. ----
+    def free_body(i, carry):
+        op = op_ref[i]
+        c = jnp.clip(cls_ref[i], 0, C - 1)
+        arg = arg_ref[i]
+        is_free = op == OP_FREE
 
-    # FREE_ALL owner sweep: accumulated masked-OR over the queue's FREE_ALL
-    # packets — whole VMEM-resident [C, N] vector op per packet, no sort.
-    is_fa = (is_free & (arg == FREE_ALL)).astype(jnp.int32)
-    class_grid = jax.lax.broadcasted_iota(jnp.int32, (C, N), 0)
+        @pl.when(is_free & (arg >= 0) & (arg < N))
+        def _single():
+            r = pl.ds(arg // _LANES, 1)
+            cnt_ref[c, r, :] = cnt_ref[c, r, :] + (
+                _lane_iota() == arg % _LANES).astype(jnp.int32)
 
-    def fa_body(i, whole):
-        fa_i = jax.lax.dynamic_index_in_dim(is_fa, i, keepdims=False)
-        cls_i = jax.lax.dynamic_index_in_dim(cls, i, keepdims=False)
-        lane_i = jax.lax.dynamic_index_in_dim(lane, i, keepdims=False)
-        hit = (fa_i > 0) & (class_grid == cls_i) & (owner == lane_i)
-        return whole | hit
+        @pl.when(is_free & (arg == FREE_ALL))
+        def _free_all():
+            lane = lane_ref[i]
 
-    whole_lane = jax.lax.fori_loop(0, Q, fa_body, jnp.zeros((C, N), bool))
+            def sweep(k, carry):
+                rows = chunk(k)
+                hit = (new_owner_ref[c, rows, :] == lane).astype(jnp.int32)
+                cnt_ref[c, rows, :] = cnt_ref[c, rows, :] | (hit << _FA_SHIFT)
+                return carry
+            jax.lax.fori_loop(0, chunks, sweep, 0)
+        return carry
+    jax.lax.fori_loop(0, Q, free_body, 0)
 
-    # Only currently-owned blocks free (a free of an unowned block is a
-    # nop); post-alloc owner map, so a block granted this step can be freed
-    # this step.  FREE_ALL contributes at most 1 per block (idempotent).
-    free_cnt = (single_cnt + whole_lane.astype(jnp.int32)) \
-        * (owner >= 0).astype(jnp.int32)
+    # ---- refcounted release (§12): each matched free decrements; a block
+    # returns to the stack (and drops its owner) only at refcount 0.  Only
+    # currently-owned blocks free (a free of an unowned block is a nop). ----
+    n_iota = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+    for c in range(C):
+        def release_body(k, carry, c=c):
+            rows = chunk(k)
+            owner = new_owner_ref[c, rows, :]
+            cnt = cnt_ref[c, rows, :]
+            drops = ((cnt & _COUNT_MASK) + (cnt >> _FA_SHIFT)) \
+                * (owner >= 0).astype(jnp.int32)
+            dec = new_refcount_ref[c, rows, :] - drops
+            ret = (drops > 0) & (dec <= 0)
+            new_refcount_ref[c, rows, :] = jnp.maximum(dec, 0)
+            new_owner_ref[c, rows, :] = jnp.where(ret, -1, owner)
+            cnt_ref[c, rows, :] = ret.astype(jnp.int32)
+            return carry
+        jax.lax.fori_loop(0, chunks, release_body, 0)
 
-    # Refcounted free (DESIGN.md §12): each matched free decrements; the
-    # block returns to the stack (and drops its owner) only at refcount 0.
-    dec = refcount - free_cnt
-    ret_mask = (free_cnt > 0) & (dec <= 0)
-    new_refcount_ref[...] = jnp.maximum(dec, 0)
+        # Append the returned ids in ascending order at the post-alloc top:
+        # row t's k returned ids go to stack positions [d, d + k), d = top +
+        # (ids returned by earlier rows).  Within the row, cum[n] counts the
+        # returned ids <= n, and the id of rank q is #{n : cum[n] <= q}.
+        top_after = new_top_ref[c]
 
-    # Compact RETURNED ids per class and append to the stack.
-    blk_ids = jax.lax.broadcasted_iota(jnp.int32, (C, N), 1)
-    freed_per_class = jnp.sum(ret_mask, axis=1).astype(jnp.int32)
-    dest = top_after_alloc[:, None] + jnp.cumsum(ret_mask, axis=1) - ret_mask
-    dest = jnp.where(ret_mask, dest, N)                     # OOB -> dropped
-    new_stack_ref[...] = stack.at[class_grid.reshape(-1), dest.reshape(-1)].set(
-        blk_ids.reshape(-1), mode="drop")
-    new_owner_ref[...] = jnp.where(ret_mask, -1, owner)
+        def append_body(t, base, c=c):
+            m = cnt_ref[c, pl.ds(t, 1), :]                          # [1, 128]
+            k = jnp.sum(m)
 
-    # ---- counters ----
-    new_top_ref[...] = (top_after_alloc + freed_per_class)[:, None]
-    new_used_ref[...] = (used_after_alloc - freed_per_class)[:, None]
-    new_alloc_ref[...] = (alloc_cnt_ref[:, 0] + taken_per_class)[:, None]
-    new_free_ref[...] = (free_cnt_ref[:, 0] + freed_per_class)[:, None]
-    new_fail_ref[...] = (fail_cnt_ref[:, 0]
-                         + jnp.sum(fail[:, None] * onehot, axis=0))[:, None]
+            @pl.when(k > 0)
+            def _append():
+                cum = jnp.sum(jnp.where(r_iota <= n_iota, m, 0), axis=1,
+                              keepdims=True)                         # [128, 1]
+                d = top_after + base
+                for s in range(2):            # the k ids span <= 2 rows
+                    q = d // _LANES + s
+                    rank = q * _LANES + _lane_iota() - d             # [1, 128]
+                    ids = t * _LANES + jnp.sum(
+                        (cum <= rank).astype(jnp.int32), axis=0,
+                        keepdims=True)
+                    put = (rank >= 0) & (rank < k)
+
+                    @pl.when((q < T) & jnp.any(put))
+                    def _put():
+                        rq = pl.ds(q, 1)
+                        new_stack_ref[c, rq, :] = jnp.where(
+                            put, ids, new_stack_ref[c, rq, :])
+            return base + k
+        freed = jax.lax.fori_loop(0, T, append_body, jnp.int32(0))
+
+        # ---- counters ----
+        taken = top_ref[c] - top_after
+        used_after_alloc = used_ref[c] + taken
+        new_peak_ref[c] = jnp.maximum(peak_ref[c], used_after_alloc)
+        new_used_ref[c] = used_after_alloc - freed
+        new_top_ref[c] = top_after + freed
+        new_alloc_ref[c] = alloc_cnt_ref[c] + taken
+        new_free_ref[c] = free_cnt_ref[c] + freed
 
 
 def fused_step_kernel(
@@ -222,47 +274,54 @@ def fused_step_kernel(
 ):
     """One fused launch for a whole scheduled HMQ burst.
 
-    The four queue vectors (op / lane / size_class / arg) ride as
-    SCALAR-PREFETCH operands (``pltpu.PrefetchScalarGridSpec``): prefetched
-    into SMEM before the body runs, and — being runtime operands rather
-    than compile-time constants — carrying whatever (possibly traced)
-    namespaced class ids the burst staged, so ONE compiled kernel serves
-    every engine shard (DESIGN.md §13).  Bit-identical to the previous
-    VMEM-operand layout in interpret mode (the differential suites).
+    The four queue vectors and the [C] counters ride in SMEM; being runtime
+    operands rather than compile-time constants, the queue carries whatever
+    (possibly traced) namespaced class ids the burst staged, so ONE compiled
+    kernel serves every engine shard (DESIGN.md §13).
 
-    Returns ``(new_stack [C,N], new_top [C,1], new_owner [C,N],
-    new_refcount [C,N], new_alloc [C,1], new_free [C,1], new_fail [C,1],
-    new_used [C,1], new_peak [C,1], blocks [Q,R], ok [Q])``.
+    Returns ``(new_stack [C,N], new_top [C], new_owner [C,N],
+    new_refcount [C,N], new_alloc [C], new_free [C], new_fail [C],
+    new_used [C], new_peak [C], blocks [Q,R], ok [Q])``.
     """
     Q = op.shape[0]
     C, N = free_stack.shape
     R = max_per_req
-    kernel = functools.partial(_kernel, num_classes=C, max_per_req=R)
-    # index maps receive (grid idx, *scalar_prefetch_refs); blocks ignore both
-    q_spec = pl.BlockSpec((Q,), lambda i, *_: (0,))
-    cn_spec = pl.BlockSpec((C, N), lambda i, *_: (0, 0))
-    c1_spec = pl.BlockSpec((C, 1), lambda i, *_: (0, 0))
-    cn_shape = jax.ShapeDtypeStruct((C, N), jnp.int32)
-    c1_shape = jax.ShapeDtypeStruct((C, 1), jnp.int32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,            # op, lane, size_class, arg
-        grid=(1,),
-        in_specs=[cn_spec, c1_spec, cn_spec, cn_spec,
-                  c1_spec, c1_spec, c1_spec, c1_spec, c1_spec],
-        out_specs=[cn_spec, c1_spec, cn_spec, cn_spec,
-                   c1_spec, c1_spec, c1_spec, c1_spec, c1_spec,
-                   pl.BlockSpec((Q, R), lambda i, *_: (0, 0)), q_spec],
-    )
-    return pl.pallas_call(
+    n_pad = -(-N // _TILE) * _TILE
+    T = n_pad // _LANES
+
+    def plane(x, fill):
+        x = jnp.pad(x.astype(jnp.int32), ((0, 0), (0, n_pad - N)),
+                    constant_values=fill)
+        return x.reshape(C, T, _LANES)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    plane_shape = jax.ShapeDtypeStruct((C, T, _LANES), jnp.int32)
+    c_shape = jax.ShapeDtypeStruct((C,), jnp.int32)
+    # The scoped limit covers the scratch plane and row temporaries; XLA
+    # places the six operand planes in VMEM outside it (DESIGN.md §8).
+    vmem_limit = C * n_pad * 4 + (8 << 20)
+    kernel = functools.partial(_kernel, num_blocks=N, max_per_req=R)
+    outs = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=[cn_shape, c1_shape, cn_shape, cn_shape,
-                   c1_shape, c1_shape, c1_shape, c1_shape, c1_shape,
-                   jax.ShapeDtypeStruct((Q, R), jnp.int32),
-                   jax.ShapeDtypeStruct((Q,), jnp.int32)],
+        in_specs=[smem] * 10 + [vmem] * 3,
+        out_specs=[vmem] * 3 + [smem] * 8,
+        out_shape=[plane_shape] * 3 + [c_shape] * 6 + [
+            jax.ShapeDtypeStruct((Q * R,), jnp.int32),
+            jax.ShapeDtypeStruct((Q,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((C, T, _LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(op.astype(jnp.int32), lane.astype(jnp.int32),
       size_class.astype(jnp.int32), arg.astype(jnp.int32),
-      free_stack, free_top[:, None], owner, refcount,
-      alloc_count[:, None], free_count[:, None], fail_count[:, None],
-      used[:, None], peak_used[:, None])
+      free_top, alloc_count, free_count, fail_count, used, peak_used,
+      plane(free_stack, 0), plane(owner, -1), plane(refcount, 0))
+    (new_stack, new_owner, new_refcount, new_top, new_alloc, new_free,
+     new_fail, new_used, new_peak, blocks, ok) = outs
+
+    def unplane(x):
+        return x.reshape(C, n_pad)[:, :N]
+
+    return (unplane(new_stack), new_top, unplane(new_owner),
+            unplane(new_refcount), new_alloc, new_free, new_fail, new_used,
+            new_peak, blocks.reshape(Q, R), ok)

@@ -40,6 +40,7 @@ from ..serve.engine import ServingEngine, run_admission
 from ..serve.multi_engine import MultiEngine
 from ..serve.router import ROUTER_POLICIES
 from ..serve.scheduler import Request, Scheduler, make_scheduler_config
+from .compile_cache import enable_compile_cache
 
 
 def synth_requests(cfg, n: int, rng: np.random.RandomState,
@@ -297,6 +298,7 @@ def main() -> None:
                     help="open-loop window budget (smoke-run bound)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch)
     rng = np.random.RandomState(args.seed)

@@ -29,7 +29,8 @@ def test_paged_attention_kernel(rng, B, KV, G, hd, ps, P, dtype, window):
     vp = jnp.asarray(rng.randn(npages, ps, KV, hd), dtype)
     tables = jnp.asarray(rng.permutation(npages)[:B * P].reshape(B, P), jnp.int32)
     seq = jnp.asarray(rng.randint(1, P * ps - 1, size=B), jnp.int32)
-    out_k = paged_decode_attention_op(q, kp, vp, tables, seq, window=window)
+    out_k = paged_decode_attention_op(q, kp, vp, tables, seq, window=window,
+                                      interpret=True)
     out_r = paged_decode_attention_op(q, kp, vp, tables, seq, window=window,
                                       impl="ref")
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -50,7 +51,7 @@ def test_flash_attention_kernel(rng, Tq, Tk, H, KV, hd, bq, bk, causal,
     k = jnp.asarray(rng.randn(B, Tk, KV, hd), dtype)
     v = jnp.asarray(rng.randn(B, Tk, KV, hd), dtype)
     a = flash_attention_op(q, k, v, causal=causal, window=window,
-                           block_q=bq, block_k=bk)
+                           block_q=bq, block_k=bk, interpret=True)
     b = flash_attention_op(q, k, v, causal=causal, window=window, impl="ref")
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(a, np.float32),

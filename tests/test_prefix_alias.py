@@ -279,9 +279,10 @@ def test_paged_attention_reads_shared_block_tables(rng, impl):
     vp2 = vp.at[10].set(vp[0]).at[11].set(vp[1])
     private = jnp.asarray([[0, 1, 2, -1], [10, 11, 3, -1]], jnp.int32)
 
-    out_shared = paged_decode_attention_op(q, kp, vp, shared, seq, impl=impl)
+    out_shared = paged_decode_attention_op(q, kp, vp, shared, seq, impl=impl,
+                                           interpret=True)
     out_private = paged_decode_attention_op(q, kp2, vp2, private, seq,
-                                            impl=impl)
+                                            impl=impl, interpret=True)
     assert np.array_equal(np.asarray(out_shared), np.asarray(out_private))
     # and kernel agrees with ref on the shared layout itself
     out_ref = paged_decode_attention_op(q, kp, vp, shared, seq, impl="ref")
