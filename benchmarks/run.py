@@ -11,7 +11,7 @@ from . import (fig08_single_thread, fig09_multithread, fig10_l2_miss,
                fig11_atomics, fig12_memory, fig13_energy,
                fig14_l1d_sensitivity, fig15_cache_partition,
                fig16_l2_capacity, fig17_icmalloc, roofline_report,
-               serving_alloc, table3_speedups)
+               table3_speedups)
 
 MODULES = {
     "fig08": fig08_single_thread,
@@ -26,7 +26,6 @@ MODULES = {
     "fig16": fig16_l2_capacity,
     "fig17": fig17_icmalloc,
     "roofline": roofline_report,
-    "serving": serving_alloc,
 }
 
 

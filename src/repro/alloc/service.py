@@ -110,7 +110,7 @@ class BurstStats(NamedTuple):
 
     ``queue_live`` / ``queue_capacity`` measure burst occupancy — how full
     the fixed-capacity HMQ batch actually was (the multi-tenant packing
-    metric tracked in ``BENCH_serving.json``).
+    metric).
     """
 
     core: StepStats
@@ -628,7 +628,7 @@ class AllocService:
         ``"e0/kv_pages"`` + ``"e1/kv_pages"`` -> one ``"kv_pages"`` row with
         summed quota/used/counters and an ``engines`` count — the
         many-clients-one-core view of the multi-engine deployment
-        (DESIGN.md §10; BENCH_serving.json ``cross_engine`` block).
+        (DESIGN.md §10).
         """
         out: dict[str, dict] = {}
         for t, rep in zip(self.tenants,

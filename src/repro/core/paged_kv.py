@@ -38,6 +38,7 @@ import dataclasses
 import functools
 from typing import TYPE_CHECKING, Callable, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -594,125 +595,128 @@ def decode_append(
     Returns ``(state, DecodeStats)`` — plus ``PendingDecodeOps`` when
     ``defer_refill`` is set.
     """
-    ps = cfg.page_size
-    L = cfg.max_lanes
-    S = cfg.stash_size
-    pos = state.seq_lens                                     # [lanes]
-    lane_ids = jnp.arange(L, dtype=jnp.int32)
-    needs_page = state.active & (pos % ps == 0) \
-        & (pos // ps < cfg.max_pages_per_lane)   # table range guard
+    with jax.named_scope("alloc"):
+        ps = cfg.page_size
+        L = cfg.max_lanes
+        S = cfg.stash_size
+        pos = state.seq_lens                                     # [lanes]
+        lane_ids = jnp.arange(L, dtype=jnp.int32)
+        needs_page = state.active & (pos % ps == 0) \
+            & (pos // ps < cfg.max_pages_per_lane)   # table range guard
 
-    # --- tier 1: pop the boundary page from the stash (no allocator step)
-    stash = state.stash
-    if S:
-        stash, popped, got_stash = stash_pop(stash, needs_page)
-        missed = needs_page & ~got_stash
-    else:
-        popped = jnp.full((L,), NO_BLOCK, jnp.int32)
-        got_stash = jnp.zeros((L,), bool)
-        missed = needs_page
-
-    # --- SWA page recycling: dead pages push to the stash first; only
-    # overflow (stash full / stash off) goes back through the central tier.
-    if window is not None:
-        # After appending at `pos`, tokens < pos+1-window are dead.  A page p
-        # (covering [p*ps, (p+1)*ps)) is dead when (p+1)*ps <= pos+1-window.
-        dead_page_idx = (pos + 1 - window) // ps - 1         # highest fully-dead page
-        has_dead = state.active & (dead_page_idx >= 0) & ((dead_page_idx + 1) * ps <= pos + 1 - window)
-        # Free exactly the newest dead page each step (at most one page can
-        # newly die per appended token), read from the block table.
-        safe_idx = jnp.clip(dead_page_idx, 0, cfg.max_pages_per_lane - 1)
-        dead_block = state.block_tables[lane_ids, safe_idx]
-        already = dead_block == NO_BLOCK                     # freed in a previous step
-        recycle = has_dead & ~already
+        # --- tier 1: pop the boundary page from the stash (no allocator step)
+        stash = state.stash
         if S:
-            stash, pushed = stash_push(stash, dead_block, recycle)
-            overflow = recycle & ~pushed                     # stash full: flush
+            stash, popped, got_stash = stash_pop(stash, needs_page)
+            missed = needs_page & ~got_stash
         else:
-            overflow = recycle
-        # the dead page leaves the table whether it was stashed or flushed
-        block_tables = state.block_tables.at[
-            jnp.where(recycle, lane_ids, L), safe_idx
-        ].set(NO_BLOCK, mode="drop")
-    else:
-        overflow = None
-        block_tables = state.block_tables
+            popped = jnp.full((L,), NO_BLOCK, jnp.int32)
+            got_stash = jnp.zeros((L,), bool)
+            missed = needs_page
 
-    # --- tier 2: one bulk HMQ burst (emergency + refill + flush), gated.
-    # In defer mode the burst carries ONLY the on-path emergency mallocs;
-    # refills and flushes accumulate in the caller's burst window.
-    tenants = tenants if tenants is not None else paged_tenants(cfg)
-    svc, kv = tenants.service, tenants.kv
-    burst = svc.new_burst()
-    t_malloc = burst.malloc(kv, lane_ids, 1, where=missed)
-    below = below_watermark(stash, state.active, cfg.stash_watermark) \
-        if S else jnp.zeros((L,), bool)
-    if S and not defer_refill:
-        # refill priority: scheduled after every plain malloc in the batch,
-        # so a bulk refill can never starve another lane's boundary
-        # allocation.
-        t_refill = burst.refill(kv, lane_ids, cfg.stash_refill, where=below)
-    if overflow is not None and not defer_refill:
-        burst.free(kv, lane_ids, dead_block, where=overflow)
-    alloc, res = svc.commit(
-        state.alloc, burst,
-        max_blocks_per_req=max(1, cfg.stash_refill if S and not defer_refill
-                               else 1),
-        backend=backend, policy=policy, gated=True)
+        # --- SWA page recycling: dead pages push to the stash first; only
+        # overflow (stash full / stash off) goes back through the central tier.
+        if window is not None:
+            # After appending at `pos`, tokens < pos+1-window are dead.  A page p
+            # (covering [p*ps, (p+1)*ps)) is dead when (p+1)*ps <= pos+1-window.
+            dead_page_idx = (pos + 1 - window) // ps - 1         # highest fully-dead page
+            has_dead = state.active & (dead_page_idx >= 0) & ((dead_page_idx + 1) * ps <= pos + 1 - window)
+            # Free exactly the newest dead page each step (at most one page can
+            # newly die per appended token), read from the block table.
+            safe_idx = jnp.clip(dead_page_idx, 0, cfg.max_pages_per_lane - 1)
+            dead_block = state.block_tables[lane_ids, safe_idx]
+            already = dead_block == NO_BLOCK                     # freed in a previous step
+            recycle = has_dead & ~already
+            if S:
+                stash, pushed = stash_push(stash, dead_block, recycle)
+                overflow = recycle & ~pushed                     # stash full: flush
+            else:
+                overflow = recycle
+            # the dead page leaves the table whether it was stashed or flushed
+            block_tables = state.block_tables.at[
+                jnp.where(recycle, lane_ids, L), safe_idx
+            ].set(NO_BLOCK, mode="drop")
+        else:
+            overflow = None
+            block_tables = state.block_tables
 
-    # --- install newly obtained pages into block tables (stash pop wins;
-    # emergency grants cover the misses)
-    new_blocks = res.blocks_for(t_malloc)[:, 0]              # [lanes]
-    e_got = res.ok_for(t_malloc) & missed
-    got = got_stash | e_got
-    page_for_lane = jnp.where(got_stash, popped, new_blocks)
-    tbl_idx = jnp.clip(pos // ps, 0, cfg.max_pages_per_lane - 1)
-    block_tables = block_tables.at[
-        jnp.where(got, lane_ids, L), tbl_idx
-    ].set(jnp.where(got, page_for_lane, NO_BLOCK), mode="drop")
+        # --- tier 2: one bulk HMQ burst (emergency + refill + flush), gated.
+        # In defer mode the burst carries ONLY the on-path emergency mallocs;
+        # refills and flushes accumulate in the caller's burst window.
+        tenants = tenants if tenants is not None else paged_tenants(cfg)
+        svc, kv = tenants.service, tenants.kv
+        burst = svc.new_burst()
+        t_malloc = burst.malloc(kv, lane_ids, 1, where=missed)
+        below = below_watermark(stash, state.active, cfg.stash_watermark) \
+            if S else jnp.zeros((L,), bool)
+        if S and not defer_refill:
+            # refill priority: scheduled after every plain malloc in the batch,
+            # so a bulk refill can never starve another lane's boundary
+            # allocation.
+            t_refill = burst.refill(kv, lane_ids, cfg.stash_refill, where=below)
+        if overflow is not None and not defer_refill:
+            burst.free(kv, lane_ids, dead_block, where=overflow)
+        alloc, res = svc.commit(
+            state.alloc, burst,
+            max_blocks_per_req=max(1, cfg.stash_refill if S and not defer_refill
+                                   else 1),
+            backend=backend, policy=policy, gated=True)
 
-    # --- install bulk-refill grants into the stash
-    if S and not defer_refill:
-        r_got = res.ok_for(t_refill) & below
-        stash = stash_push_batch(stash,
-                                 res.blocks_for(t_refill)[:, :cfg.stash_refill],
-                                 cfg.stash_refill, r_got)
-        refill_failed = jnp.sum(below & ~r_got).astype(jnp.int32)
-    else:
-        # deferred refills fail (benignly) at the window commit, not here
-        refill_failed = jnp.zeros((), jnp.int32)
+        # --- install newly obtained pages into block tables (stash pop wins;
+        # emergency grants cover the misses)
+        new_blocks = res.blocks_for(t_malloc)[:, 0]              # [lanes]
+        e_got = res.ok_for(t_malloc) & missed
+        got = got_stash | e_got
+        page_for_lane = jnp.where(got_stash, popped, new_blocks)
+        tbl_idx = jnp.clip(pos // ps, 0, cfg.max_pages_per_lane - 1)
+        block_tables = block_tables.at[
+            jnp.where(got, lane_ids, L), tbl_idx
+        ].set(jnp.where(got, page_for_lane, NO_BLOCK), mode="drop")
 
-    # --- write the new token's K/V into each lane's current page
-    writable = state.active & (got | ~needs_page)
-    cur_block = block_tables[lane_ids, tbl_idx]              # [lanes]
-    offset = pos % ps
-    dst_page = jnp.where(writable & (cur_block != NO_BLOCK), cur_block, cfg.num_pages)
-    # scatter: k_pages[dst_page, :, offset] = new_k[lane]
-    k_pages = state.k_pages.at[dst_page, :, offset].set(
-        new_k.astype(cfg.dtype), mode="drop")
-    v_pages = state.v_pages.at[dst_page, :, offset].set(
-        new_v.astype(cfg.dtype), mode="drop")
+        # --- install bulk-refill grants into the stash
+        if S and not defer_refill:
+            r_got = res.ok_for(t_refill) & below
+            stash = stash_push_batch(stash,
+                                     res.blocks_for(t_refill)[:, :cfg.stash_refill],
+                                     cfg.stash_refill, r_got)
+            refill_failed = jnp.sum(below & ~r_got).astype(jnp.int32)
+        else:
+            # deferred refills fail (benignly) at the window commit, not here
+            refill_failed = jnp.zeros((), jnp.int32)
 
-    new = state._replace(
-        alloc=alloc,
-        block_tables=block_tables,
-        seq_lens=jnp.where(writable, pos + 1, pos),
-        k_pages=k_pages,
-        v_pages=v_pages,
-        stash=stash,
-    )
-    dstats = DecodeStats(
-        core=res.stats.core,
-        tenant=res.stats.per_tenant,
-        failed=jnp.sum(missed & ~e_got).astype(jnp.int32),
-        refill_failed=refill_failed,
-        stash_hits=jnp.sum(got_stash).astype(jnp.int32),
-        stash_misses=jnp.sum(missed).astype(jnp.int32),
-        bursts=res.live,
-        stash_depth_hist=stash_depth_histogram(cfg, stash, state.active),
-        queue_live=res.stats.queue_live,
-        queue_capacity=res.stats.queue_capacity,
-    )
+    with jax.named_scope("kv_write"):
+        # --- write the new token's K/V into each lane's current page
+        writable = state.active & (got | ~needs_page)
+        cur_block = block_tables[lane_ids, tbl_idx]              # [lanes]
+        offset = pos % ps
+        dst_page = jnp.where(writable & (cur_block != NO_BLOCK), cur_block, cfg.num_pages)
+        # scatter: k_pages[dst_page, :, offset] = new_k[lane]
+        k_pages = state.k_pages.at[dst_page, :, offset].set(
+            new_k.astype(cfg.dtype), mode="drop")
+        v_pages = state.v_pages.at[dst_page, :, offset].set(
+            new_v.astype(cfg.dtype), mode="drop")
+
+    with jax.named_scope("alloc"):
+        new = state._replace(
+            alloc=alloc,
+            block_tables=block_tables,
+            seq_lens=jnp.where(writable, pos + 1, pos),
+            k_pages=k_pages,
+            v_pages=v_pages,
+            stash=stash,
+        )
+        dstats = DecodeStats(
+            core=res.stats.core,
+            tenant=res.stats.per_tenant,
+            failed=jnp.sum(missed & ~e_got).astype(jnp.int32),
+            refill_failed=refill_failed,
+            stash_hits=jnp.sum(got_stash).astype(jnp.int32),
+            stash_misses=jnp.sum(missed).astype(jnp.int32),
+            bursts=res.live,
+            stash_depth_hist=stash_depth_histogram(cfg, stash, state.active),
+            queue_live=res.stats.queue_live,
+            queue_capacity=res.stats.queue_capacity,
+        )
     if not defer_refill:
         return new, dstats
     if overflow is not None:

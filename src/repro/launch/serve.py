@@ -66,22 +66,18 @@ def synth_requests(cfg, n: int, rng: np.random.RandomState,
 def serve_loop(eng: ServingEngine, sched: Scheduler,
                requests: list[Request], max_new_tokens: int,
                log_every: int = 8, verbose: bool = True,
-               step_times_us: list | None = None,
                preemption: bool = False) -> int:
     """Drive the scheduler/engine lifecycle until every request completes.
 
-    Returns the number of decode steps taken.  When ``step_times_us`` is
-    given, per-decode-step wall times (µs) are appended to it (benchmark
-    hook).  If admission starves with nothing running — the pool cannot fit
-    any waiting request — the loop stops and reports the stranded requests
-    loudly rather than silently undercounting.  ``preemption`` enables the
+    Returns the number of decode steps taken.  If admission starves with
+    nothing running — the pool cannot fit any waiting request — the loop
+    stops and reports the stranded requests loudly rather than silently
+    undercounting.  ``preemption`` enables the
     scheduler's priority eviction (DESIGN.md §10): when a waiting request
     outranks a running one and admission is stuck, the lowest-priority
     running lane is FREE_ALLed and its request re-queued with its generated
     prefix.
     """
-    import time
-
     for req in requests:
         req.max_new_tokens = max_new_tokens
         sched.submit(req)
@@ -94,10 +90,7 @@ def serve_loop(eng: ServingEngine, sched: Scheduler,
                 continue     # whole batch retired at the admission seed
                              # (max_new_tokens == 1): admit the next one
             break                      # nothing admissible: pool too small
-        t0 = time.perf_counter()
         tokens = eng.step()
-        if step_times_us is not None:
-            step_times_us.append((time.perf_counter() - t0) * 1e6)
         step += 1
         finished = sched.note_decode_step(tokens)
         if finished:

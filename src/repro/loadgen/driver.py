@@ -65,7 +65,7 @@ class OpenLoopReport:
     requests_per_s: float
 
     def as_metrics(self) -> dict:
-        """Flat dict for BENCH_serving.json."""
+        """Flat dict of the report's fields, floats rounded."""
         return {k: (round(v, 3) if isinstance(v, float) else v)
                 for k, v in dataclasses.asdict(self).items()}
 

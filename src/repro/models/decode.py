@@ -69,46 +69,53 @@ def paged_decode_attention(
 
 def _attn_layer_step(cfg: ArchConfig, lp: dict, x, kvcfg, paged: PagedKVState,
                      kv_layer, window, positions):
-    """One attention block for one new token. Returns (x, k_new, v_new)."""
+    """One attention block for one new token. Returns (x, k_new, v_new).
+
+    Named scopes ``attention`` (with ``gather_kv`` inside) and ``mlp`` mark
+    its device ops in the compiled step's metadata and in profiler traces."""
     hd = cfg.resolved_head_dim
     B = x.shape[0]
-    h = apply_norm(cfg.norm, lp["ln_attn"], x)
-    q = (h @ lp["attn"]["wq"] + lp["attn"].get("bq", 0.0)).reshape(B, cfg.num_heads, hd)
-    k = (h @ lp["attn"]["wk"] + lp["attn"].get("bk", 0.0)).reshape(B, cfg.num_kv_heads, hd)
-    v = (h @ lp["attn"]["wv"] + lp["attn"].get("bv", 0.0)).reshape(B, cfg.num_kv_heads, hd)
-    if cfg.family != "audio":
-        q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-        k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-    from ..distributed.hints import current_hints
-    from ..perf_flags import current_flags
-    hints = current_hints()
-    flags = current_flags()
-    static_window = getattr(cfg, "window", None)
-    use_windowed = (flags.windowed_gather and static_window
-                    and cfg.attn_pattern == "swa")
-    if use_windowed:
-        # SWA: gather only the slots that can be live under the window —
-        # the support-core already recycled everything older (DESIGN.md §2)
-        from ..core.paged_kv import gather_kv_window
-        k_gath, v_gath, pos, gvalid = gather_kv_window(
-            kvcfg, paged, kv_layer, static_window)
-    else:
-        k_gath, v_gath, _ = gather_kv(kvcfg, paged, kv_layer)
-        pos = gvalid = None
-    k_gath = hints.gathered_kv(k_gath, cfg.num_kv_heads)
-    v_gath = hints.gathered_kv(v_gath, cfg.num_kv_heads)
-    attn = paged_decode_attention(q, k_gath, v_gath, k, v,
-                                  paged.seq_lens, paged.active, window,
-                                  pos=pos, gathered_valid=gvalid)
-    x = x + out_project(lp["attn"], attn[:, None])[:, 0]
-    h = apply_norm(cfg.norm, lp["ln_mlp"], x)
-    if "moe" in lp:
-        spec = MoESpec(cfg.d_model, cfg.d_ff, cfg.num_experts,
-                       cfg.experts_per_token,
-                       capacity_factor=cfg.moe_capacity_factor, act=cfg.act)
-        x = x + moe_apply(lp["moe"], spec, h[:, None])[:, 0]
-    else:
-        x = x + mlp_apply(lp["mlp"], h, cfg.act)
+    with jax.named_scope("attention"):
+        h = apply_norm(cfg.norm, lp["ln_attn"], x)
+        q = (h @ lp["attn"]["wq"] + lp["attn"].get("bq", 0.0)).reshape(B, cfg.num_heads, hd)
+        k = (h @ lp["attn"]["wk"] + lp["attn"].get("bk", 0.0)).reshape(B, cfg.num_kv_heads, hd)
+        v = (h @ lp["attn"]["wv"] + lp["attn"].get("bv", 0.0)).reshape(B, cfg.num_kv_heads, hd)
+        if cfg.family != "audio":
+            q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+            k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+        from ..distributed.hints import current_hints
+        from ..perf_flags import current_flags
+        hints = current_hints()
+        flags = current_flags()
+        static_window = getattr(cfg, "window", None)
+        use_windowed = (flags.windowed_gather and static_window
+                        and cfg.attn_pattern == "swa")
+        with jax.named_scope("gather_kv"):
+            if use_windowed:
+                # SWA: gather only the slots that can be live under the
+                # window — the support-core already recycled everything
+                # older (DESIGN.md §2)
+                from ..core.paged_kv import gather_kv_window
+                k_gath, v_gath, pos, gvalid = gather_kv_window(
+                    kvcfg, paged, kv_layer, static_window)
+            else:
+                k_gath, v_gath, _ = gather_kv(kvcfg, paged, kv_layer)
+                pos = gvalid = None
+            k_gath = hints.gathered_kv(k_gath, cfg.num_kv_heads)
+            v_gath = hints.gathered_kv(v_gath, cfg.num_kv_heads)
+        attn = paged_decode_attention(q, k_gath, v_gath, k, v,
+                                      paged.seq_lens, paged.active, window,
+                                      pos=pos, gathered_valid=gvalid)
+        x = x + out_project(lp["attn"], attn[:, None])[:, 0]
+    with jax.named_scope("mlp"):
+        h = apply_norm(cfg.norm, lp["ln_mlp"], x)
+        if "moe" in lp:
+            spec = MoESpec(cfg.d_model, cfg.d_ff, cfg.num_experts,
+                           cfg.experts_per_token,
+                           capacity_factor=cfg.moe_capacity_factor, act=cfg.act)
+            x = x + moe_apply(lp["moe"], spec, h[:, None])[:, 0]
+        else:
+            x = x + mlp_apply(lp["mlp"], h, cfg.act)
     return x, k, v
 
 
@@ -232,14 +239,17 @@ def decode_hidden(
             lp, cp, w, li = xs
             h, k, v = _attn_layer_step(cfg, lp, h, kvcfg, paged, li, w, positions)
             # cross attention over encoder output (dense, non-paged)
-            hd = cfg.resolved_head_dim
-            B = h.shape[0]
-            hn = apply_norm(cfg.norm, cp["ln"], h)
-            q = (hn @ cp["attn"]["wq"]).reshape(B, cfg.num_heads, hd)
-            ck = (enc_out @ cp["attn"]["wk"]).reshape(B, -1, cfg.num_kv_heads, hd)
-            cv = (enc_out @ cp["attn"]["wv"]).reshape(B, -1, cfg.num_kv_heads, hd)
-            cattn = mea_attention(q[:, None], ck, cv, causal=False)[:, 0]
-            h = h + out_project(cp["attn"], cattn[:, None])[:, 0]
+            with jax.named_scope("attention"):
+                hd = cfg.resolved_head_dim
+                B = h.shape[0]
+                hn = apply_norm(cfg.norm, cp["ln"], h)
+                q = (hn @ cp["attn"]["wq"]).reshape(B, cfg.num_heads, hd)
+                ck = (enc_out @ cp["attn"]["wk"]).reshape(
+                    B, -1, cfg.num_kv_heads, hd)
+                cv = (enc_out @ cp["attn"]["wv"]).reshape(
+                    B, -1, cfg.num_kv_heads, hd)
+                cattn = mea_attention(q[:, None], ck, cv, causal=False)[:, 0]
+                h = h + out_project(cp["attn"], cattn[:, None])[:, 0]
             return h, (k, v)
 
         h, (ks, vs) = jax.lax.scan(

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..configs.base import ArchConfig
 from ..core import paged_kv as pkv
 from ..core.paged_kv import PagedKVConfig
@@ -28,6 +29,40 @@ from .scheduler import (SchedulerConfig, make_scheduler_config, pick_bucket,
 from .serve_step import (CountingJit, ServeState, init_serve_state,
                          make_decode_step, make_family_prefill,
                          recycle_window)
+
+#: JAX's event around every executable it compiles or loads from the
+#: persistent compilation cache
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_HOST_READS = {"repro.admission": "admission_host_reads",
+               "repro.release": "release_host_reads",
+               "repro.decode": "decode_host_reads"}
+_COMPILES = {"repro.admission": "admission_compiles",
+             "repro.release": "release_compiles"}
+_listening = False
+
+
+def _stage(name: str) -> str:
+    """``repro.admission.plan`` -> ``repro.admission``."""
+    return ".".join(name.split(".", 2)[:2])
+
+
+def _count_compile(event: str, duration: float, **kw) -> None:
+    """Credit a compile to the owner of the innermost ``repro.*`` span."""
+    top = obs.innermost()
+    if event != _COMPILE_EVENT or top is None or top[1] is None:
+        return
+    field = _COMPILES.get(_stage(top[0]))
+    if field is not None:
+        setattr(top[1], field, getattr(top[1], field) + 1)
+
+
+def _listen_for_compiles() -> None:
+    """Register ``_count_compile`` once per process (JAX listeners cannot
+    be told apart once registered)."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_count_compile)
+        _listening = True
 
 
 @dataclasses.dataclass
@@ -83,12 +118,20 @@ class EngineStats:
     extent_pages: int = 0          # pages covered by those runs
     compactions: int = 0           # between-window compaction passes run
     compaction_moves: int = 0      # pages migrated by those passes
+    # --- host work per lifecycle stage (DESIGN.md §16) ---
+    # blocking device->host reads (``ServingEngine._fetch``) and executables
+    # compiled or loaded from the persistent cache, each credited to the
+    # stage whose ``repro.*`` span is innermost when it happens
+    decode_host_reads: int = 0
+    admission_host_reads: int = 0
+    release_host_reads: int = 0
+    admission_compiles: int = 0
+    release_compiles: int = 0
 
     @property
     def hit_admit_us(self) -> float:
         """Mean wall-clock microseconds per admission batch that contained
-        at least one prefix-cache hit — the copy-vs-alias speedup metric
-        (BENCH_serving.json)."""
+        at least one prefix-cache hit — the copy-vs-alias speedup metric."""
         if not self.cache_hit_admits:
             return 0.0
         return self.cache_hit_admit_us / self.cache_hit_admits
@@ -96,14 +139,14 @@ class EngineStats:
     @property
     def cache_hit_rate(self) -> float:
         """Fraction of admission-time probes that found a reusable cached
-        prefix (tracked in BENCH_serving.json; 0.0 with the cache off)."""
+        prefix (0.0 with the cache off)."""
         total = self.cache_hits + self.cache_misses
         return self.cache_hits / total if total else 0.0
 
     @property
     def mean_run_len(self) -> float:
         """Mean contiguous-run length of admitted KV pages (pages per
-        extent; 1.0 == every page an island — BENCH_serving.json)."""
+        extent; 1.0 == every page an island)."""
         if not self.contiguous_extents:
             return 0.0
         return self.extent_pages / self.contiguous_extents
@@ -126,7 +169,7 @@ class EngineStats:
     def burst_occupancy(self) -> float:
         """Mean fraction of HMQ slots carrying a live packet per issued
         burst — how well multi-tenant traffic packs the fixed-capacity
-        queue (tracked in BENCH_serving.json)."""
+        queue."""
         if not self.burst_slots_capacity:
             return 0.0
         return self.burst_slots_live / self.burst_slots_capacity
@@ -161,18 +204,29 @@ def run_admission(eng: "ServingEngine", sched, preemption: bool = False,
     running lanes) before resorting to preemption.  Requests the admission
     seed finishes are demoted back into the cache on release.
     """
+    with obs.span("repro.admission", owner=eng.stats) as sp:
+        return _admission_pass(eng, sched, sp, preemption, after_op)
+
+
+def _admission_pass(eng: "ServingEngine", sched, sp, preemption: bool,
+                    after_op) -> bool:
     sync = after_op if after_op is not None else (lambda: None)
     probe = eng.cache_probe if eng.cache is not None else None
     alias = eng.alias_enabled
-    plan = sched.plan_admission(eng.free_pages, probe=probe, alias=alias)
+
+    def plan_pass():
+        with obs.span("repro.admission.plan", owner=eng.stats):
+            return sched.plan_admission(eng.free_pages, probe=probe,
+                                        alias=alias)
+
+    plan = plan_pass()
     if not plan.size and eng.cache is not None and eng.cache.pages:
         short = sched.head_shortfall(eng.free_pages)
         if short is not None and eng.cache_release(short):
             sync()
             # evicting may have shortened the head's cached prefix — replan
             # so cached_len/bucket/page math all reflect the new cache state
-            plan = sched.plan_admission(eng.free_pages, probe=probe,
-                                        alias=alias)
+            plan = plan_pass()
     if not plan.size and preemption:
         lane = sched.preempt_victim(free_pages=eng.free_pages)
         if lane is not None:
@@ -181,12 +235,13 @@ def run_admission(eng: "ServingEngine", sched, preemption: bool = False,
             eng.preempt([lane])
             sync()
             sched.preempt(lane)
-            plan = sched.plan_admission(eng.free_pages, probe=probe,
-                                        alias=alias)
+            plan = plan_pass()
     if not plan.size:
         return False
     items = [AdmissionItem(lane, r.tokens, r.frames, r.patches, r.cached_len)
              for b in plan.batches for lane, r in b.items]
+    sp.set(lanes=[it.lane for it in items],
+           rids=[r.rid for b in plan.batches for _, r in b.items])
     failed = eng.admit_many(items)      # failed lanes come back reclaimed
     sync()
     sched.commit_admission(plan)
@@ -306,8 +361,22 @@ class ServingEngine:
         self._prefill_cache: dict[tuple, Any] = {}
         self.stats = EngineStats()
         self.window = recycle_window(cfg)
+        _listen_for_compiles()
 
     # ---------------- multi-tenant telemetry ----------------
+
+    def _fetch(self, value):
+        """One blocking device->host read of ``value``: an array, or a tuple
+        of pytrees read in one transfer.  Counted in
+        ``stats.<stage>_host_reads`` for the stage whose ``repro.*`` span
+        is innermost (none outside the engine's spans)."""
+        top = obs.innermost()
+        field = _HOST_READS.get(_stage(top[0])) if top is not None else None
+        if field is not None:
+            setattr(self.stats, field, getattr(self.stats, field) + 1)
+        if isinstance(value, tuple):
+            return jax.device_get(value)
+        return np.asarray(value)
 
     def _note_burst(self, per_tenant, queue_live=None, queue_capacity=None,
                     issued: bool = True) -> None:
@@ -315,8 +384,12 @@ class ServingEngine:
         occupancy, when the burst was actually issued) into EngineStats."""
         # one device->host transfer for everything, not one blocking scalar
         # fetch per (field, tenant) — this runs every decode step
-        pt, queue_live, queue_capacity = jax.device_get(
-            (per_tenant, queue_live, queue_capacity))
+        self._fold_burst(*self._fetch((per_tenant, queue_live,
+                                       queue_capacity)), issued=issued)
+
+    def _fold_burst(self, pt, queue_live, queue_capacity,
+                    issued: bool = True) -> None:
+        """``_note_burst`` on values already on the host."""
         for t in self.tenants.handles:
             d = self.stats.tenants.setdefault(t.name, {
                 "mallocs": 0, "failed": 0, "blocks_allocated": 0,
@@ -423,16 +496,20 @@ class ServingEngine:
         path.  Returns how many pages were actually freed."""
         blocks = self.cache.evict_pages(n_pages)
         if blocks:
-            pkts = release_packet_array([], self.kvcfg.max_lanes)
-            paged, stats = pkv.release_packets(
-                self.kvcfg, self.state.paged, jnp.asarray(pkts),
-                backend=self.alloc_backend, policy=self.alloc_policy,
-                tenants=self.tenants, extra_free=blocks)
-            self.stats.hmq_release_bursts += 1
-            self._note_burst(stats.per_tenant, stats.queue_live,
-                             stats.queue_capacity)
-            self.state = self.state._replace(paged=paged)
-            self._sync_cache_stats()
+            with obs.span("repro.release", owner=self.stats,
+                          evicted=len(blocks)):
+                with obs.span("repro.release.burst", owner=self.stats):
+                    pkts = release_packet_array([], self.kvcfg.max_lanes)
+                    paged, stats = pkv.release_packets(
+                        self.kvcfg, self.state.paged, jnp.asarray(pkts),
+                        backend=self.alloc_backend, policy=self.alloc_policy,
+                        tenants=self.tenants, extra_free=blocks)
+                    self.stats.hmq_release_bursts += 1
+                with obs.span("repro.release.telemetry", owner=self.stats):
+                    self._note_burst(stats.per_tenant, stats.queue_live,
+                                     stats.queue_capacity)
+                    self.state = self.state._replace(paged=paged)
+                    self._sync_cache_stats()
         return len(blocks)
 
     def _demote_lanes(self, kv_tokens: dict) -> list[int]:
@@ -447,7 +524,7 @@ class ServingEngine:
         burst.  MUST run before the release commit.
         """
         ps = self.kvcfg.page_size
-        tbl = np.asarray(self.state.paged.block_tables)
+        tbl = self._fetch(self.state.paged.block_tables)
         retag: list[int] = []
         evicted: list[int] = []
         for lane, toks in kv_tokens.items():
@@ -599,8 +676,10 @@ class ServingEngine:
                         [pe, np.zeros((width - k,) + pe.shape[1:], pe.dtype)])
                 batch["patches"] = jnp.asarray(pe, self.dtype)
 
-            res = self._prefill_fn(
-                (bucket, n_prefix, width, cached_len))(self.params, batch)
+            with obs.span("repro.admission.prefill", owner=self.stats,
+                          bucket=bucket, rows=k):
+                res = self._prefill_fn(
+                    (bucket, n_prefix, width, cached_len))(self.params, batch)
 
             rows = np.arange(k)
             lanes = np.asarray([it.lane for it in group], np.int32)
@@ -640,69 +719,72 @@ class ServingEngine:
                 kv_chunks.append((ks[rows], vs[rows]))
 
         order = np.argsort(np.asarray(all_lanes, np.int32))
-        lanes_arr = jnp.asarray(np.asarray(all_lanes, np.int32)[order])
-        next_tokens = jnp.concatenate(all_next)[jnp.asarray(order)]
-
-        if kv_chunks:
-            # Pad every bucket's KV to the widest time extent, then ONE burst.
-            t_max = max(c[0].shape[2] for c in kv_chunks)
-            ks = jnp.concatenate(
-                [jnp.pad(c[0], ((0, 0), (0, 0), (0, t_max - c[0].shape[2]),
-                                (0, 0), (0, 0))) for c in kv_chunks])
-            vs = jnp.concatenate(
-                [jnp.pad(c[1], ((0, 0), (0, 0), (0, t_max - c[1].shape[2]),
-                                (0, 0), (0, 0))) for c in kv_chunks])
-            perm = jnp.asarray(order)
-            kv_lens = jnp.asarray(np.asarray(all_kv_len, np.int32)[order])
-            pb = pl = None
-            if lane_prefix:
-                # burst-order [B, P] cache pages + [B] aliased token counts;
-                # rows with no hit carry zeros (inert: the splice and the
-                # refcount bump both mask on prefix length)
-                lanes_np = np.asarray(all_lanes, np.int32)[order]
-                P = max(len(b) for b, _ in lane_prefix.values())
-                pb_np = np.zeros((lanes_np.shape[0], P), np.int32)
-                pl_np = np.zeros((lanes_np.shape[0],), np.int32)
-                for r, lane in enumerate(lanes_np):
-                    rec = lane_prefix.get(int(lane))
-                    if rec is not None:
-                        pb_np[r, : len(rec[0])] = rec[0]
-                        pl_np[r] = len(rec[0]) * self.kvcfg.page_size
-                pb, pl = jnp.asarray(pb_np), jnp.asarray(pl_np)
-            paged, stats = pkv.admit_prefill_many(
-                self.kvcfg, self.state.paged, lanes_arr,
-                ks[perm], vs[perm], kv_lens, backend=self.alloc_backend,
-                policy=self.alloc_policy, tenants=self.tenants,
-                prefix_blocks=pb, prefix_lens=pl)
-            self.stats.hmq_admit_bursts += 1
-            self.stats.alloc_failures += int(stats.failed)
-            self._note_burst(stats.per_tenant, stats.queue_live,
-                             stats.queue_capacity)
+        lanes_np = np.asarray(all_lanes, np.int32)[order]
+        with obs.span("repro.admission.kv_install", owner=self.stats):
+            lanes_arr = jnp.asarray(lanes_np)
+            next_tokens = jnp.concatenate(all_next)[jnp.asarray(order)]
+            stats = None
+            if kv_chunks:
+                # Pad every bucket's KV to the widest time extent, then ONE
+                # burst.
+                t_max = max(c[0].shape[2] for c in kv_chunks)
+                ks = jnp.concatenate(
+                    [jnp.pad(c[0], ((0, 0), (0, 0), (0, t_max - c[0].shape[2]),
+                                    (0, 0), (0, 0))) for c in kv_chunks])
+                vs = jnp.concatenate(
+                    [jnp.pad(c[1], ((0, 0), (0, 0), (0, t_max - c[1].shape[2]),
+                                    (0, 0), (0, 0))) for c in kv_chunks])
+                perm = jnp.asarray(order)
+                kv_lens = jnp.asarray(np.asarray(all_kv_len, np.int32)[order])
+                pb = pl = None
+                if lane_prefix:
+                    # burst-order [B, P] cache pages + [B] aliased token
+                    # counts; rows with no hit carry zeros (inert: the splice
+                    # and the refcount bump both mask on prefix length)
+                    P = max(len(b) for b, _ in lane_prefix.values())
+                    pb_np = np.zeros((lanes_np.shape[0], P), np.int32)
+                    pl_np = np.zeros((lanes_np.shape[0],), np.int32)
+                    for r, lane in enumerate(lanes_np):
+                        rec = lane_prefix.get(int(lane))
+                        if rec is not None:
+                            pb_np[r, : len(rec[0])] = rec[0]
+                            pl_np[r] = len(rec[0]) * self.kvcfg.page_size
+                    pb, pl = jnp.asarray(pb_np), jnp.asarray(pl_np)
+                paged, stats = pkv.admit_prefill_many(
+                    self.kvcfg, self.state.paged, lanes_arr,
+                    ks[perm], vs[perm], kv_lens, backend=self.alloc_backend,
+                    policy=self.alloc_policy, tenants=self.tenants,
+                    prefix_blocks=pb, prefix_lens=pl)
+                self.stats.hmq_admit_bursts += 1
+            else:
+                # attention-free (rwkv6): no pages to allocate; activate lanes
+                paged = self.state.paged
+                kv_lens = jnp.asarray(np.asarray(all_kv_len, np.int32)[order])
+                paged = paged._replace(
+                    seq_lens=paged.seq_lens.at[lanes_arr].set(kv_lens),
+                    active=paged.active.at[lanes_arr].set(True))
+            self.state = self.state._replace(
+                paged=paged,
+                tokens=self.state.tokens.at[lanes_arr].set(next_tokens))
+        if stats is not None:
+            with obs.span("repro.admission.telemetry", owner=self.stats):
+                self.stats.alloc_failures += int(self._fetch(stats.failed))
+                self._note_burst(stats.per_tenant, stats.queue_live,
+                                 stats.queue_capacity)
+        with obs.span("repro.admission.readback", owner=self.stats):
+            ok = self._fetch(paged.active)[lanes_np]
+            if self.cfg.family not in ("ssm", "hybrid"):
+                toks = self._fetch(next_tokens)
+        failed = [int(l) for l, o in zip(lanes_np, ok) if not o]
+        if self.cfg.family in ("ssm", "hybrid"):
+            self.admitted_tokens = {}          # seed == last prompt token
         else:
-            # attention-free (rwkv6): no pages to allocate; activate lanes
-            paged = self.state.paged
-            kv_lens = jnp.asarray(np.asarray(all_kv_len, np.int32)[order])
-            paged = paged._replace(
-                seq_lens=paged.seq_lens.at[lanes_arr].set(kv_lens),
-                active=paged.active.at[lanes_arr].set(True))
-
-        self.state = self.state._replace(
-            paged=paged,
-            tokens=self.state.tokens.at[lanes_arr].set(next_tokens))
-        ok = np.asarray(paged.active)[np.asarray(lanes_arr)]
-        failed = [int(l) for l, o in zip(np.asarray(lanes_arr), ok) if not o]
-        if kv_chunks:
-            # contiguity telemetry over the lanes this batch installed: how
-            # well the policy served admission's run-grants (DESIGN.md §15)
-            ok_lanes = [int(l) for l, o in zip(np.asarray(lanes_arr), ok) if o]
-            if ok_lanes:
-                ext, pgs = pkv.extent_stats(paged.block_tables, ok_lanes)
-                self.stats.contiguous_extents += ext
-                self.stats.extent_pages += pgs
+            self.admitted_tokens = {
+                int(l): int(t) for l, t, o in zip(lanes_np, toks, ok) if o}
         if lane_prefix:
             # pin the spliced entries for every lane that actually admitted
             # (the device refcount bump was gated on the same success mask)
-            for lane, o in zip(np.asarray(lanes_arr), ok):
+            for lane, o in zip(lanes_np, ok):
                 rec = lane_prefix.get(int(lane))
                 if rec is None or not o:
                     continue
@@ -712,24 +794,27 @@ class ServingEngine:
                     toks[: len(blks) * self.kvcfg.page_size], blks)
                 self.stats.aliased_pages += len(blks)
         self.stats.admitted += len(items) - len(failed)
-        self.stats.prefill_tokens_saved += sum(
-            lane_cached.get(int(l), 0)
-            for l, o in zip(np.asarray(lanes_arr), ok) if o)
-        self._sync_cache_stats()
-        if self.cfg.family in ("ssm", "hybrid"):
-            self.admitted_tokens = {}          # seed == last prompt token
-        else:
-            toks = np.asarray(next_tokens)
-            self.admitted_tokens = {
-                int(l): int(t) for l, t, o
-                in zip(np.asarray(lanes_arr), toks, ok) if o}
+        with obs.span("repro.admission.telemetry", owner=self.stats):
+            if kv_chunks:
+                # contiguity telemetry over the lanes this batch installed:
+                # how well the policy served admission's run-grants
+                # (DESIGN.md §15)
+                ok_lanes = [int(l) for l, o in zip(lanes_np, ok) if o]
+                if ok_lanes:
+                    ext, pgs = pkv.extent_stats(
+                        self._fetch(paged.block_tables), ok_lanes)
+                    self.stats.contiguous_extents += ext
+                    self.stats.extent_pages += pgs
+            self.stats.prefill_tokens_saved += sum(
+                lane_cached.get(int(l), 0) for l, o in zip(lanes_np, ok) if o)
+            self._sync_cache_stats()
         if failed:
             # reclaim orphaned partial grants (e.g. KV pages granted while
             # the state-slot packet failed) so failure never leaks the pool
             self.release(failed, completed=False)
         if any(it.cached_len for it in items):
             # hit-admission latency, comparable across copy/alias modes
-            # (the np.asarray(active) fetch above already synced the device)
+            # (the fetch of ``active`` above already synced the device)
             self.stats.cache_hit_admits += 1
             self.stats.cache_hit_admit_us += \
                 (time.perf_counter() - t_admit0) * 1e6
@@ -773,32 +858,48 @@ class ServingEngine:
 
         In ``defer_refill`` mode the step's deferrable allocator ops are
         appended to ``pending_ops`` for the multi-engine burst window to
-        drain (one merged commit per window — DESIGN.md §10)."""
-        if self.defer_refill:
-            self.state, logits, stats, pending = self._decode(
-                self.params, self.state, self._class_ids)
-            self.pending_ops.append(pending)
-        else:
-            self.state, logits, stats = self._decode(self.params, self.state,
-                                                     self._class_ids)
-        # mirror the executable's compile accounting: with a shared
-        # multi-engine CountingJit every shard reports the SAME counter
-        # (1 executable for all of them), not a per-shard contribution
-        self.stats.decode_compiles = self._decode.compiles
-        self.stats.decode_compile_us = self._decode.compile_us
-        self.stats.decode_steps += 1
-        self.stats.alloc_failures += int(stats.failed)
-        self.stats.decode_bursts += int(stats.bursts)
-        self.stats.stash_hits += int(stats.stash_hits)
-        self.stats.stash_misses += int(stats.stash_misses)
-        self._note_burst(stats.tenant, stats.queue_live, stats.queue_capacity,
-                         issued=bool(int(stats.bursts)))
-        hist = np.asarray(stats.stash_depth_hist)
-        if not self.stats.stash_depth_hist:
-            self.stats.stash_depth_hist = [0] * hist.shape[0]
-        self.stats.stash_depth_hist = [
-            a + int(b) for a, b in zip(self.stats.stash_depth_hist, hist)]
-        return np.asarray(self.state.tokens)
+        drain (one merged commit per window — DESIGN.md §10).
+
+        Spans: ``repro.decode`` (``step`` = this step's number) with
+        ``.dispatch`` (the jitted step), ``.readback`` (every blocking
+        read) and ``.telemetry`` (the host-side folds)."""
+        st = self.stats
+        with obs.span("repro.decode", owner=st, step=st.decode_steps):
+            with obs.span("repro.decode.dispatch", owner=st):
+                if self.defer_refill:
+                    self.state, logits, stats, pending = self._decode(
+                        self.params, self.state, self._class_ids)
+                    self.pending_ops.append(pending)
+                else:
+                    self.state, logits, stats = self._decode(
+                        self.params, self.state, self._class_ids)
+            with obs.span("repro.decode.readback", owner=st):
+                failed = int(self._fetch(stats.failed))
+                bursts = int(self._fetch(stats.bursts))
+                hits = int(self._fetch(stats.stash_hits))
+                misses = int(self._fetch(stats.stash_misses))
+                burst = self._fetch((stats.tenant, stats.queue_live,
+                                     stats.queue_capacity))
+                hist = self._fetch(stats.stash_depth_hist)
+                tokens = self._fetch(self.state.tokens)
+            with obs.span("repro.decode.telemetry", owner=st):
+                # mirror the executable's compile accounting: with a shared
+                # multi-engine CountingJit every shard reports the SAME
+                # counter (1 executable for all of them), not a per-shard
+                # contribution
+                st.decode_compiles = self._decode.compiles
+                st.decode_compile_us = self._decode.compile_us
+                st.decode_steps += 1
+                st.alloc_failures += failed
+                st.decode_bursts += bursts
+                st.stash_hits += hits
+                st.stash_misses += misses
+                self._fold_burst(*burst, issued=bool(bursts))
+                if not st.stash_depth_hist:
+                    st.stash_depth_hist = [0] * hist.shape[0]
+                st.stash_depth_hist = [
+                    a + int(b) for a, b in zip(st.stash_depth_hist, hist)]
+        return tokens
 
     # ---------------- completion ----------------
 
@@ -821,30 +922,34 @@ class ServingEngine:
         the shared block ids ride this commit as single OP_FREEs, because
         their FREE_ALLs match on lane ownership and skip CACHE_OWNER pages.
         """
-        extra = None
-        if completed and self.cache is not None and kv_tokens:
-            # demote BEFORE unalias: the pins keep this insert's budget
-            # evictions away from prefix pages other live lanes still read
-            extra = self._demote_lanes(
-                {l: kv_tokens[l] for l in lanes if l in kv_tokens})
-        if self._aliased:
-            shared = self._unalias_lanes(lanes)
-            if shared:
-                extra = (extra or []) + shared
-        pkts = release_packet_array(list(lanes), self.kvcfg.max_lanes)
-        paged, stats = pkv.release_packets(self.kvcfg, self.state.paged,
-                                           jnp.asarray(pkts),
-                                           backend=self.alloc_backend,
-                                           policy=self.alloc_policy,
-                                           tenants=self.tenants,
-                                           extra_free=extra)
-        self.stats.hmq_release_bursts += 1
-        self._note_burst(stats.per_tenant, stats.queue_live,
-                         stats.queue_capacity)
-        self.state = self.state._replace(paged=paged)
-        self._sync_cache_stats()
-        if completed:
-            self.stats.completed += len(lanes)
+        st = self.stats
+        with obs.span("repro.release", owner=st, lanes=list(lanes)):
+            extra = None
+            if completed and self.cache is not None and kv_tokens:
+                # demote BEFORE unalias: the pins keep this insert's budget
+                # evictions away from prefix pages other live lanes still
+                # read
+                with obs.span("repro.release.demote", owner=st):
+                    extra = self._demote_lanes(
+                        {l: kv_tokens[l] for l in lanes if l in kv_tokens})
+            with obs.span("repro.release.burst", owner=st):
+                if self._aliased:
+                    shared = self._unalias_lanes(lanes)
+                    if shared:
+                        extra = (extra or []) + shared
+                pkts = release_packet_array(list(lanes), self.kvcfg.max_lanes)
+                paged, stats = pkv.release_packets(
+                    self.kvcfg, self.state.paged, jnp.asarray(pkts),
+                    backend=self.alloc_backend, policy=self.alloc_policy,
+                    tenants=self.tenants, extra_free=extra)
+                st.hmq_release_bursts += 1
+            with obs.span("repro.release.telemetry", owner=st):
+                self._note_burst(stats.per_tenant, stats.queue_live,
+                                 stats.queue_capacity)
+                self.state = self.state._replace(paged=paged)
+                self._sync_cache_stats()
+            if completed:
+                st.completed += len(lanes)
 
     def preempt(self, lanes: Sequence[int]) -> None:
         """Evict running lanes: FREE_ALL every block they own (pages, state
@@ -857,9 +962,10 @@ class ServingEngine:
 
     @property
     def live_pages(self) -> int:
-        return int(pkv.live_pages(self.state.paged, self.tenants))
+        return int(self._fetch(pkv.live_pages(self.state.paged, self.tenants)))
 
     @property
     def free_pages(self) -> int:
         """Allocatable KV pages right now (admission-policy input)."""
-        return int(self.state.paged.alloc.free_top[self.tenants.kv.size_class])
+        return int(self._fetch(
+            self.state.paged.alloc.free_top[self.tenants.kv.size_class]))

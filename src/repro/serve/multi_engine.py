@@ -72,8 +72,7 @@ class MultiEngineStats:
     @property
     def cross_engine_burst_occupancy(self) -> float:
         """Mean fraction of merged-window HMQ slots carrying a live packet —
-        how well N engines' deferred traffic packs the shared burst
-        (BENCH_serving.json)."""
+        how well N engines' deferred traffic packs the shared burst."""
         if not self.window_slots_capacity:
             return 0.0
         return self.window_slots_live / self.window_slots_capacity
@@ -200,8 +199,7 @@ class MultiEngine:
     # ---------------- the async serving loop ----------------
 
     def serve(self, requests: Sequence[Request], max_new_tokens: int = 16,
-              validate: bool = False, verbose: bool = False,
-              step_times_us: Optional[list] = None) -> int:
+              validate: bool = False, verbose: bool = False) -> int:
         """Drive every request to completion; returns total burst windows.
 
         ``validate`` runs the full shared-state invariant check (I1–I4 over
@@ -211,8 +209,7 @@ class MultiEngine:
         self.submit(requests, max_new_tokens=max_new_tokens)
         windows = 0
         while self.has_work:
-            progressed = self.step_window(validate=validate,
-                                          step_times_us=step_times_us)
+            progressed = self.step_window(validate=validate)
             windows += 1
             if verbose:
                 done = sum(len(s.finished) for s in self.scheds)
@@ -226,13 +223,10 @@ class MultiEngine:
                 break
         return windows
 
-    def step_window(self, validate: bool = False,
-                    step_times_us: Optional[list] = None) -> bool:
+    def step_window(self, validate: bool = False) -> bool:
         """One burst window: admission (+preemption), a quantum of decode
         steps on every shard, then ONE merged window commit.  Returns
         whether any shard made progress (admitted or decoded)."""
-        import time
-
         progressed = False
         # --- admission + preemption phase (one admission burst per shard:
         # prefill compute and the KV install are inherently per-shard; the
@@ -258,10 +252,7 @@ class MultiEngine:
                 if not sched.running:
                     continue
                 eng = self._sync(i)
-                t0 = time.perf_counter()
                 tokens = eng.step()
-                if step_times_us is not None:
-                    step_times_us.append((time.perf_counter() - t0) * 1e6)
                 self._pull(i)
                 self.stats.decode_steps += 1
                 progressed = True

@@ -43,6 +43,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs
 from ..configs.base import ArchConfig
 from ..core.packets import NO_LANE
 from ..core.paged_kv import PagedKVConfig
@@ -357,12 +358,13 @@ class Scheduler:
         prefix and callers need for the final response payload.
         """
         done = []
-        for lane, req in self.running.items():
-            req.generated += 1
-            if tokens is not None:
-                req.output.append(int(tokens[lane]))
-            if req.generated >= req.max_new_tokens:
-                done.append(lane)
+        with obs.span("repro.sched.step"):
+            for lane, req in self.running.items():
+                req.generated += 1
+                if tokens is not None:
+                    req.output.append(int(tokens[lane]))
+                if req.generated >= req.max_new_tokens:
+                    done.append(lane)
         return done
 
     def release_packet_array(self, lanes: list[int]) -> np.ndarray:

@@ -142,8 +142,9 @@ def make_decode_step(cfg: ArchConfig, kvcfg: PagedKVConfig,
         hidden, new_kv, new_rec = decode_hidden(
             params, cfg, kvcfg, state.paged, state.rec, state.tokens,
             enc_out=state.enc_out, hints=hints, unroll=unroll)
-        logits = decode_logits(params, cfg, hidden)
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("logits"):
+            logits = decode_logits(params, cfg, hidden)
+            next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         pending = None
         if new_kv is not None:
