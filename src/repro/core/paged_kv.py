@@ -558,7 +558,37 @@ class PendingDecodeOps(NamedTuple):
     flush_blocks: jnp.ndarray  # [L] int32 — their block ids (NO_BLOCK else)
 
 
-def decode_append(
+class KVWrite(NamedTuple):
+    """Where one decode step's new K/V goes: row ``lane`` of ``k``/``v``
+    lands at ``k_pages[dst_page[lane], :, offset[lane]]``.  ``dst_page`` is
+    ``num_pages`` (out of range, dropped) for lanes that write nothing."""
+
+    dst_page: jnp.ndarray      # [lanes] int32
+    offset: jnp.ndarray        # [lanes] int32
+    k: jnp.ndarray             # [lanes, L, kv_heads, head_dim] pool dtype
+    v: jnp.ndarray
+
+
+def write_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray, w: KVWrite):
+    """Scatter one decode step's K/V rows into the pool.  Jitted with both
+    pools donated, it writes them in place (DESIGN.md §16)."""
+    with jax.named_scope("kv_write"):
+        return (k_pages.at[w.dst_page, :, w.offset].set(w.k, mode="drop"),
+                v_pages.at[w.dst_page, :, w.offset].set(w.v, mode="drop"))
+
+
+def decode_append(cfg: PagedKVConfig, state: PagedKVState,
+                  new_k: jnp.ndarray, new_v: jnp.ndarray, **kw):
+    """:func:`decode_alloc` followed by its :func:`write_kv`: one appended
+    token per active lane, pool included.  Returns what ``decode_alloc``
+    does, less the ``KVWrite``."""
+    state, stats, w, *pending = decode_alloc(cfg, state, new_k, new_v, **kw)
+    k_pages, v_pages = write_kv(state.k_pages, state.v_pages, w)
+    return (state._replace(k_pages=k_pages, v_pages=v_pages), stats,
+            *pending)
+
+
+def decode_alloc(
     cfg: PagedKVConfig,
     state: PagedKVState,
     new_k: jnp.ndarray,           # [max_lanes, L, kv_heads, head_dim]
@@ -569,7 +599,8 @@ def decode_append(
     tenants: Optional[PagedTenants] = None,
     defer_refill: bool = False,
 ):
-    """Append one token per active lane through the two-tier allocator.
+    """Advance every active lane by one token through the two-tier
+    allocator, and say where its K/V goes; the pool itself is left alone.
 
     Tier 1 (stash, when ``cfg.stash_size > 0``): page-boundary lanes pop
     their new page from the per-lane stash with pure vector ops, and
@@ -592,8 +623,9 @@ def decode_append(
     central stack and lane stashes, and flushed dead pages stay owner-mapped
     (hence reclaimable by ``FREE_ALL``) until the window commit frees them.
 
-    Returns ``(state, DecodeStats)`` — plus ``PendingDecodeOps`` when
-    ``defer_refill`` is set.
+    Returns ``(state, DecodeStats, KVWrite)`` — plus ``PendingDecodeOps``
+    when ``defer_refill`` is set.  ``state`` holds the input's pool arrays
+    unchanged; :func:`write_kv` applies the ``KVWrite`` to them.
     """
     with jax.named_scope("alloc"):
         ps = cfg.page_size
@@ -685,24 +717,18 @@ def decode_append(
             refill_failed = jnp.zeros((), jnp.int32)
 
     with jax.named_scope("kv_write"):
-        # --- write the new token's K/V into each lane's current page
+        # --- each lane's new token goes to its current page
         writable = state.active & (got | ~needs_page)
         cur_block = block_tables[lane_ids, tbl_idx]              # [lanes]
-        offset = pos % ps
         dst_page = jnp.where(writable & (cur_block != NO_BLOCK), cur_block, cfg.num_pages)
-        # scatter: k_pages[dst_page, :, offset] = new_k[lane]
-        k_pages = state.k_pages.at[dst_page, :, offset].set(
-            new_k.astype(cfg.dtype), mode="drop")
-        v_pages = state.v_pages.at[dst_page, :, offset].set(
-            new_v.astype(cfg.dtype), mode="drop")
+        write = KVWrite(dst_page=dst_page, offset=pos % ps,
+                        k=new_k.astype(cfg.dtype), v=new_v.astype(cfg.dtype))
 
     with jax.named_scope("alloc"):
         new = state._replace(
             alloc=alloc,
             block_tables=block_tables,
             seq_lens=jnp.where(writable, pos + 1, pos),
-            k_pages=k_pages,
-            v_pages=v_pages,
             stash=stash,
         )
         dstats = DecodeStats(
@@ -718,7 +744,7 @@ def decode_append(
             queue_capacity=res.stats.queue_capacity,
         )
     if not defer_refill:
-        return new, dstats
+        return new, dstats, write
     if overflow is not None:
         pending = PendingDecodeOps(
             below=below, flush_mask=overflow,
@@ -727,7 +753,7 @@ def decode_append(
         pending = PendingDecodeOps(
             below=below, flush_mask=jnp.zeros((L,), bool),
             flush_blocks=jnp.full((L,), NO_BLOCK, jnp.int32))
-    return new, dstats, pending
+    return new, dstats, write, pending
 
 
 def stash_depth_histogram(cfg: PagedKVConfig, stash: LaneStashState,

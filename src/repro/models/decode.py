@@ -5,7 +5,7 @@ each attention layer gathers its page-mapped KV (the *only* data-path read of
 allocator-managed storage; metadata never enters the compute path, per the
 paper's segregated layout) — collect the new token's K/V per layer, then hand
 the whole batch of page requests to the support-core in ONE HMQ step
-(`decode_append`).
+(`decode_alloc`).
 
 Families:
   dense/moe/vlm — paged attention every layer

@@ -28,7 +28,7 @@ from .scheduler import (SchedulerConfig, make_scheduler_config, pick_bucket,
                         release_packet_array)
 from .serve_step import (CountingJit, ServeState, init_serve_state,
                          make_decode_step, make_family_prefill,
-                         recycle_window)
+                         recycle_window, write_kv_in_place)
 
 #: JAX's event around every executable it compiles or loads from the
 #: persistent compilation cache
@@ -70,6 +70,8 @@ class EngineStats:
     admitted: int = 0
     completed: int = 0
     decode_steps: int = 0
+    kv_writes_in_place: int = 0    # decode steps whose K/V write consumed
+    #                                the donated pool (DESIGN.md §16)
     preemptions: int = 0           # running lanes evicted by the scheduler
     alloc_failures: int = 0        # failed malloc packets (all families)
     hmq_admit_bursts: int = 0      # support-core steps issued for admission
@@ -853,6 +855,27 @@ class ServingEngine:
 
     # ---------------- decode ----------------
 
+    def decode_step(self):
+        """Run the jitted decode step on ``self.state``, then write its K/V
+        into the pool in place.  Returns ``(logits, DecodeStats)``; in
+        ``defer_refill`` mode the step's deferrable allocator ops go to
+        ``pending_ops``."""
+        # the live pool, taken before the step: the step hands its state
+        # back without one, and a stand-in ``_decode`` may hand back an
+        # older state whose pool is no longer live
+        k, v = self.state.paged.k_pages, self.state.paged.v_pages
+        new, logits, stats, *pending = self._decode(
+            self.params, self.state, self._class_ids)
+        self.pending_ops.extend(pending)
+        if new.kv_write is not None:
+            written = write_kv_in_place(k, v, new.kv_write)
+            # donation marks the input deleted at dispatch: no device sync
+            self.stats.kv_writes_in_place += int(k.is_deleted())
+            k, v = written
+        self.state = new._replace(
+            paged=new.paged._replace(k_pages=k, v_pages=v), kv_write=None)
+        return logits, stats
+
     def step(self) -> np.ndarray:
         """One decode step for all active lanes; returns next tokens.
 
@@ -861,18 +884,12 @@ class ServingEngine:
         drain (one merged commit per window — DESIGN.md §10).
 
         Spans: ``repro.decode`` (``step`` = this step's number) with
-        ``.dispatch`` (the jitted step), ``.readback`` (every blocking
-        read) and ``.telemetry`` (the host-side folds)."""
+        ``.dispatch`` (the jitted step and its K/V write), ``.readback``
+        (every blocking read) and ``.telemetry`` (the host-side folds)."""
         st = self.stats
         with obs.span("repro.decode", owner=st, step=st.decode_steps):
             with obs.span("repro.decode.dispatch", owner=st):
-                if self.defer_refill:
-                    self.state, logits, stats, pending = self._decode(
-                        self.params, self.state, self._class_ids)
-                    self.pending_ops.append(pending)
-                else:
-                    self.state, logits, stats = self._decode(
-                        self.params, self.state, self._class_ids)
+                _, stats = self.decode_step()
             with obs.span("repro.decode.readback", owner=st):
                 failed = int(self._fetch(stats.failed))
                 bursts = int(self._fetch(stats.bursts))

@@ -13,7 +13,7 @@ protocol (DESIGN.md §2):
   prompt length), and the engine admits the whole batch with ONE
   ``admit_prefill_many`` HMQ burst — the paper's batched "server-client"
   (Larson) admission instead of one synchronized burst per sequence.
-* **Decode** — ``decode_append``'s two-tier fast path: page boundaries pop
+* **Decode** — ``decode_alloc``'s two-tier fast path: page boundaries pop
   the per-lane stash, and at most ONE bulk HMQ burst per step carries
   refills/flushes (skipped entirely when no packet is live — DESIGN.md §7).
   The page budget charges each admission's stash pre-charge

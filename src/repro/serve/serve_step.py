@@ -2,8 +2,12 @@
 
 The decode step is the production home of the SpeedMalloc technique: every
 step the layer stack reads paged KV via block tables (segregated metadata),
-and ends with exactly ONE support-core HMQ batch (`decode_append`) carrying
+and ends with exactly ONE support-core HMQ batch (`decode_alloc`) carrying
 all page mallocs (page-boundary lanes) and frees (slid-out SWA pages).
+
+The decode step only reads the KV pool.  It hands back the new token's K/V
+and where it goes (`ServeState.kv_write`), and `write_kv_in_place` writes
+them into the donated pool (DESIGN.md §16).
 """
 from __future__ import annotations
 
@@ -15,8 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ArchConfig
-from ..core.paged_kv import (PagedKVConfig, PagedKVState, PendingDecodeOps,
-                             decode_append, empty_decode_stats, init_paged_kv)
+from ..core.paged_kv import (KVWrite, PagedKVConfig, PagedKVState,
+                             PendingDecodeOps, decode_alloc,
+                             empty_decode_stats, init_paged_kv, write_kv)
 from ..distributed.hints import use_hints
 from ..models.decode import (RecurrentState, decode_hidden, decode_logits,
                              init_recurrent_state)
@@ -30,6 +35,14 @@ class ServeState(NamedTuple):
     tokens: jnp.ndarray                  # [lanes] last sampled token
     enc_out: Optional[jnp.ndarray] = None  # [lanes, F, d] whisper encoder output
     step: jnp.ndarray = None             # scalar int32
+    # set only on a decode step's output, whose paged.k_pages/v_pages are
+    # None: the K/V the step leaves for ``write_kv_in_place``
+    kv_write: Optional[KVWrite] = None
+
+
+#: The one place the KV pool changes during decode: one step's K/V scattered
+#: into both pools, which are donated, so XLA writes them in place.
+write_kv_in_place = jax.jit(write_kv, donate_argnums=(0, 1))
 
 
 def recycle_window(cfg: ArchConfig) -> Optional[int]:
@@ -114,6 +127,12 @@ def make_decode_step(cfg: ArchConfig, kvcfg: PagedKVConfig,
                      traced_classes: bool = False):
     """Returns serve_step(params, state) -> (state, logits, DecodeStats).
 
+    The returned state carries no pool (``paged.k_pages``/``v_pages`` are
+    None) and, for families with paged KV, the step's ``kv_write``: the
+    caller puts the input's pool back, written by ``write_kv_in_place``.
+    Returning the pool would make XLA copy it, since the step cannot write
+    into an input it was not given.
+
     ``alloc_backend`` selects the support-core implementation for the
     decode burst (``jnp`` | ``kernel`` | ``kernel-interpret``; None resolves
     ``REPRO_ALLOC_BACKEND`` at trace time — see DESIGN.md §8);
@@ -146,18 +165,18 @@ def make_decode_step(cfg: ArchConfig, kvcfg: PagedKVConfig,
             logits = decode_logits(params, cfg, hidden)
             next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        pending = None
+        pending = kv_write = None
         if new_kv is not None:
             new_k, new_v = new_kv
-            out = decode_append(
+            out = decode_alloc(
                 kvcfg, state.paged,
                 new_k.astype(kvcfg.dtype), new_v.astype(kvcfg.dtype),
                 window=window, backend=alloc_backend, policy=alloc_policy,
                 tenants=step_tenants, defer_refill=defer_refill)
             if defer_refill:
-                paged, stats, pending = out
+                paged, stats, kv_write, pending = out
             else:
-                paged, stats = out
+                paged, stats, kv_write = out
         else:
             # attention-free (rwkv6): no pages; still advance lane clocks
             paged = state.paged._replace(
@@ -171,8 +190,9 @@ def make_decode_step(cfg: ArchConfig, kvcfg: PagedKVConfig,
                     flush_blocks=jnp.full((L,), -1, jnp.int32))
 
         new_state = ServeState(
-            paged=paged, rec=new_rec, tokens=next_tokens,
-            enc_out=state.enc_out, step=state.step + 1)
+            paged=paged._replace(k_pages=None, v_pages=None), rec=new_rec,
+            tokens=next_tokens, enc_out=state.enc_out, step=state.step + 1,
+            kv_write=kv_write)
         if defer_refill:
             return new_state, logits, stats, pending
         return new_state, logits, stats

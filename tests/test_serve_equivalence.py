@@ -49,10 +49,8 @@ def test_decode_matches_forward(arch, rng):
     for t in range(n_decode):
         eng.state = eng.state._replace(
             tokens=eng.state.tokens.at[0].set(int(toks[n_prefill + t])))
-        # the decode step is tenant-agnostic (DESIGN.md §13): the engine's
-        # class ids ride in as a traced operand, not trace-time constants
-        eng.state, logits, _ = eng._decode(eng.params, eng.state,
-                                           eng._class_ids)
+        # the jitted step, then its K/V written into the pool in place
+        logits, _ = eng.decode_step()
         ref = forward(params, cfg, jnp.asarray(toks[:n_prefill + t + 1])[None],
                       remat=False, **fkw)
         ref_last = np.asarray(ref[0, -1])

@@ -75,3 +75,47 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip):
         _spec((B, P), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip)).compile()
     _check(compiled)
+
+
+def test_decode_step_leaves_the_pool_to_the_in_place_write(one_chip):
+    """deepseek-7b widths with 2 layers, 8 lanes, seq_len 512, pages of 16,
+    bf16: the decode executable has no pool-sized output and copies no
+    pool; the donated write aliases both pools to its outputs."""
+    import dataclasses
+    import re
+
+    import repro.core.paged_kv as pkv
+    from repro.configs.base import get_config
+    from repro.models import abstract_params, make_paged_config
+    from repro.serve.serve_step import (init_serve_state, make_decode_step,
+                                        write_kv_in_place)
+
+    bf16 = jnp.bfloat16
+    cfg = dataclasses.replace(get_config("deepseek-7b"), num_layers=2)
+    kvcfg = make_paged_config(cfg, seq_len=512, lanes=8, page_size=16,
+                              dtype=bf16)
+    tenants = pkv.paged_tenants(kvcfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(abstract_params(cfg, bf16))
+    state = on_chip(jax.eval_shape(
+        lambda: init_serve_state(cfg, kvcfg, 8, 0, bf16)))
+    class_ids = _spec((len(tenants.handles),), jnp.int32, one_chip)
+    step = make_decode_step(cfg, kvcfg, alloc_backend="jnp",
+                            tenants=tenants, traced_classes=True)
+    decode = jax.jit(step).lower(params, state, class_ids).compile()
+
+    pool = state.paged.k_pages
+    pool_bytes = pool.size * pool.dtype.itemsize
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    copies = re.findall(rf"= {re.escape(shape)}\S* copy\(", decode.as_text())
+    assert copies == []
+    assert decode.memory_analysis().output_size_in_bytes < pool_bytes
+
+    kv_write = on_chip(jax.eval_shape(step, params, state,
+                                      class_ids)[0].kv_write)
+    write = write_kv_in_place.lower(pool, state.paged.v_pages,
+                                    kv_write).compile()
+    assert write.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
