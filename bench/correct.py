@@ -22,6 +22,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import spec
+
 SAMPLE = 12      # requests compared with the reference in each run
 
 
@@ -66,8 +68,8 @@ def logit_gaps(model: dict, seed: int, recs: list, pad_to: int,
                quant: str | None = None):
     """Per request: the reference's gap at each served token, and (with
     ``quant``) the gap at the token the lower precision puts first."""
-    from .reference import forward_gaps
     from .weights import make_weights
+    forward_gaps = spec.family(model).forward_gaps
     weights = make_weights(model, seed)
     seqs = [np.concatenate([r.tokens, np.asarray(r.output[:-1], np.int32)])
             for r in recs]

@@ -50,6 +50,7 @@ class Step:
     t: float          # when the step's tokens reached the host
     active: int       # lanes that made a token
     kv_tokens: int    # sum over those lanes of the positions they attend
+    kv_lens: tuple    # the positions each of those lanes attends
 
 
 @dataclasses.dataclass
@@ -113,11 +114,11 @@ class Driver:
         with self.span("bench.decode"):
             toks = self.eng.step()
             tc = time.perf_counter() - t0
-            kv = 0
+            lens = []
             for req in running:
                 recs[req.rid].times.append(tc)
-                kv += req.prompt_len + len(req.output)
-            steps.append(Step(tc, len(running), kv))
+                lens.append(req.prompt_len + len(req.output))
+            steps.append(Step(tc, len(running), sum(lens), tuple(lens)))
             finished = self.sched.note_decode_step(toks)
             if self._pool_class is not None:
                 self.kv_peak = max(self.kv_peak, int(

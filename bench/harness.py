@@ -147,7 +147,7 @@ def build(cell, model: dict, seed: int, seconds: float,
     from .warmup import warm_up
     from .weights import make_weights
 
-    cfg = spec.arch_config(model)
+    cfg = spec.family(model).arch_config(model)
     srv = model["serving"]
     dtype = jnp.dtype(srv["dtype"])
     kvcfg = make_paged_config(cfg, seq_len=srv["seq_len"],

@@ -1,29 +1,45 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` at the repository
 root names the cell's configuration and mix, and each lives in a file of
-its own under ``bench/``."""
+its own under ``bench/``.
+
+What the benchmark knows of a model's shape lives in one module per
+family, ``bench/families/<family>.py``, which ``family`` loads by path
+from the configuration file's ``"family"`` key.  There is no default: a
+file without the key, or one that names a family with no file, is
+refused.  A family module provides
+
+* ``arch_config(model)``: the program's ``ArchConfig`` for the
+  configuration file, refusing what the program cannot run as stated;
+* ``layout(model)``: the weight leaves as ``(name, shape)``, under the
+  names of the program's parameter tree (``bench/weights.py`` draws them:
+  1-D leaves and names holding ``norm`` or ``/ln_`` are gains stored as
+  ``gain - 1``, ``embed`` has std 0.02, every other matrix std
+  ``1/sqrt(shape[-2])``);
+* ``forward_gaps(model, weights, seqs, targets, pad_to, block, quant)``:
+  the plain float32 reference, built on ``bench/reference.py``; per
+  sequence, each target's gap below the best logit and the token it
+  puts first, and with ``quant="fp8"`` the control;
+* ``decode_flops(model, steps)``: model FLOPs of the decode steps, a list
+  of ``bench.driver.Step`` (``kv_lens`` gives each lane's positions, for
+  layers that attend fewer than all of them);
+* ``SMOKE``: configuration keys that cut it to the size of the CPU tests.
+
+So a model of a new family joins the benchmark as new files: its family
+module, its configuration, and its cells' traffic and limits.
+"""
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH_DIR = ROOT / "bench"
 
-#: HF config key -> repro ArchConfig field, for the keys a cell may set
-HF_FIELDS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "num_hidden_layers": "num_layers",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-}
-#: what the program's dense decoder fixes and the file has to state
-PROGRAM_FIXED = {"rms_norm_eps": 1e-6, "hidden_act": "silu",
-                 "tie_word_embeddings": False}
+#: one module per model family, found by the configuration's "family"
+FAMILY_DIR = BENCH_DIR / "families"
 
 
 def load_benchmark() -> dict:
@@ -63,17 +79,22 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
                 limits=limits)
 
 
-def arch_config(model: dict):
-    """The program's ``ArchConfig`` for a configuration file, with every
-    key the file states applied to the registered architecture."""
-    from repro.configs.base import get_config
-    for key, want in PROGRAM_FIXED.items():
-        if model[key] != want:
-            raise ValueError(f"{key}={model[key]!r}: the program runs "
-                             f"{want!r} only")
-    base = get_config(model["arch"])
-    if base.family != "dense" or base.attn_pattern != "full":
-        raise ValueError(f"{model['arch']}: the reference is a dense decoder "
-                         f"with full attention")
-    return dataclasses.replace(
-        base, **{f: model[k] for k, f in HF_FIELDS.items() if k in model})
+def family(model: dict):
+    """The module ``<FAMILY_DIR>/<family>.py`` that the configuration
+    file's ``"family"`` key names."""
+    files = sorted(p.name for p in FAMILY_DIR.glob("*.py"))
+    name = model.get("family")
+    if f"{name}.py" not in files:
+        raise ValueError(f"configuration {model.get('arch')!r}: family "
+                         f"{name!r} has no file under {FAMILY_DIR}; the "
+                         f"files there: {files}")
+    return _load_family(FAMILY_DIR / f"{name}.py")
+
+
+@functools.cache
+def _load_family(path: Path):
+    mod_spec = importlib.util.spec_from_file_location(
+        f"bench.families.{path.stem}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod

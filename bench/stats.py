@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import spec
 from .trace import module_time
 
 #: the decode step is the engine's one ``CountingJit``, whose jitted
@@ -42,13 +43,11 @@ def decode_step_ms(run):
 
 
 def decode_mfu(run):
-    from .flops import decode_flops
     dev = decode_device(run)
     steps = run.window.steps
     if not dev or not steps:
         return None
-    flops = decode_flops(run.model, sum(s.active for s in steps),
-                         sum(s.kv_tokens for s in steps))
+    flops = spec.family(run.model).decode_flops(run.model, steps)
     # the traced runs' mean device time, times the steps the driver made
     return flops / (dev[0] / dev[1] * len(steps) * run.peak["bf16_flops"])
 

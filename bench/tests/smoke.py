@@ -3,16 +3,15 @@ from __future__ import annotations
 
 from bench import spec, traffic
 
-SMOKE_MODEL = dict(hidden_size=64, intermediate_size=128,
-                   num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-                   num_hidden_layers=2, vocab_size=256)
 SMOKE_SERVING = {"lanes": 2, "seq_len": 64, "page_size": 16,
                  "dtype": "bfloat16"}
 
 
 def smoke_model(cell: str) -> dict:
-    return dict(spec.load_cell(cell).model, **SMOKE_MODEL,
-                serving=SMOKE_SERVING)
+    """The cell's configuration cut to smoke size by its family's
+    ``SMOKE``, served on two lanes."""
+    model = spec.load_cell(cell).model
+    return dict(model, **spec.family(model).SMOKE, serving=SMOKE_SERVING)
 
 
 def smoke_mix(cell: str) -> dict:
